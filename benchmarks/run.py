@@ -47,6 +47,7 @@ from benchmarks import (
     table3_energy,
     table4_accuracy,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 ALL = {
     "table2": table2_throughput.main,
@@ -84,6 +85,7 @@ def main() -> None:
             sys.exit(2)
         del args[i : i + 2]
     which = args or list(ALL)
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     rows = []

@@ -86,8 +86,6 @@ class SearchSpace:
     min_size: int = 16384
     min_contract: int = 64
     score: str = "l1"
-    use_kernel: bool = False
-    interpret: bool = False
     # speculative-decode prior (spec_ks beyond 0 need a draft model)
     spec_accept: float = 0.8
     draft_n_params: int = 0
@@ -241,8 +239,6 @@ def candidate_plan_config(cand: Candidate, space: SearchSpace) -> WP.PlanConfig:
         score=space.score,
         min_size=space.min_size,
         min_contract=space.min_contract,
-        use_kernel=space.use_kernel,
-        interpret=space.interpret,
     )
 
 
@@ -890,8 +886,6 @@ def tuned_plan_doc(cfg, result: TuneResult, *, space: SearchSpace,
             "score": pc.score,
             "min_size": pc.min_size,
             "min_contract": pc.min_contract,
-            "use_kernel": pc.use_kernel,
-            "interpret": pc.interpret,
         },
         "serving": {
             "kv_dtype": result.best.kv_dtype,

@@ -83,9 +83,12 @@ ZYNQ_PRUNE = HardwareSpec(
     b_weight=2.0,
 )
 
-# TPU v5e, one chip. The MXU plays the role of the m x r MAC array:
+# TPU v5e, one chip (published peaks, Google Cloud documentation "TPU
+# v5e"). The MXU plays the role of the m x r MAC array:
 # peak 197 TFLOP/s bf16 => m*r = 197e12 / 2 / f. We fold it into f_pu=1,
-# m*r = MACs/s so the formulas carry over unchanged.
+# m*r = MACs/s so the formulas carry over unchanged.  These are the v5e's
+# numbers only: the sizer models a v5e whatever device it runs on, and
+# serve.py prints the device kind beside them.
 TPU_V5E = HardwareSpec(
     name="tpu-v5e-chip",
     m=1,

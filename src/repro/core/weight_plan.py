@@ -64,6 +64,7 @@ from typing import Any, Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.pruning import BlockPruneConfig, block_mask, expand_block_mask
 from repro.core.sparse_format import BlockSparse, build_walk, pad_walk
@@ -117,29 +118,22 @@ class PackedLinear:
     shape: tuple = ()
     bk: int = 128
     bn: int = 128
-    use_kernel: bool = False
-    interpret: bool = False
+    # None = the Pallas kernel on the TPU backend, the gather reference
+    # elsewhere (resolved at trace time, like layers.paged_decode_attention)
+    use_kernel: Optional[bool] = None
+    # logical name of the dense weight's output-feature axis (e.g. "ff"):
+    # under a mesh the kernel call shards every block-column child on it
+    out_axis: Optional[str] = None
 
     @property
     def stacked(self) -> bool:
         return self.blocks.ndim > 3
 
-    def to_block_sparse(self) -> BlockSparse:
-        """View (unstacked) as the BlockSparse the Pallas kernel consumes."""
-        assert not self.stacked
-        return BlockSparse(
-            blocks=self.blocks,
-            block_rows=self.block_rows,
-            counts=self.counts,
-            shape=self.shape,
-            cfg=BlockPruneConfig(bk=self.bk, bn=self.bn),
-        )
-
 
 jax.tree_util.register_dataclass(
     PackedLinear,
     data_fields=["blocks", "block_rows", "counts", "scales", "walk"],
-    meta_fields=["kind", "shape", "bk", "bn", "use_kernel", "interpret"],
+    meta_fields=["kind", "shape", "bk", "bn", "use_kernel", "out_axis"],
 )
 
 
@@ -214,8 +208,6 @@ class PlanConfig:
                tests so tiny configs have enough blocks to prune).
     min_size / min_contract: eligibility floor (same as quant serving: tiny
                mats stay dense — streaming them is free anyway).
-    use_kernel/interpret: route unstacked 2-D sparse matmuls through the
-               Pallas kernel (interpret=True for CPU tests).
     """
 
     default: str = "quant_sparse"
@@ -226,8 +218,6 @@ class PlanConfig:
     score: str = "l1"
     min_size: int = 16384
     min_contract: int = 64
-    use_kernel: bool = False
-    interpret: bool = False
 
     def __post_init__(self):
         if self.default not in REPRS:
@@ -343,7 +333,8 @@ def quantize_for_serving(params, min_size: int = 16384):
     return jax.tree_util.tree_map_with_path(q, params)
 
 
-def pack_block_sparse(leaf, cfg: PlanConfig, quant: bool) -> PackedLinear:
+def pack_block_sparse(leaf, cfg: PlanConfig, quant: bool,
+                      out_axis: Optional[str] = None) -> PackedLinear:
     """Prune ``leaf`` to block sparsity cfg.q_prune and pack it.
 
     Handles any leading stacking dims (scan units, MoE experts, or both):
@@ -418,8 +409,7 @@ def pack_block_sparse(leaf, cfg: PlanConfig, quant: bool) -> PackedLinear:
         shape=(K, N),
         bk=bk,
         bn=bn,
-        use_kernel=cfg.use_kernel,
-        interpret=cfg.interpret,
+        out_axis=out_axis,
     )
 
 
@@ -543,6 +533,14 @@ class WeightPlan:
         return sl.tree_shardings(
             self.params, self.axes_tree(), mesh=mesh, rules=rules)
 
+    def placed(self, mesh, rules=None) -> "WeightPlan":
+        """This plan with its packed params moved onto their shardings.
+        Packing runs on one device; placing the plan (rather than only
+        the engine's copy) leaves no whole-model copy behind there."""
+        params = jax.device_put(self.params, self.param_shardings(mesh, rules))
+        return dataclasses.replace(
+            self, params=params, _by_path=_index_nodes(params))
+
     @property
     def fused_pairs(self) -> int:
         """Gated-FFN (w_gate, w_up) pairs the fused gate+up node serves as
@@ -658,8 +656,7 @@ def _node_meta(node) -> dict:
             "shape": list(node.shape),
             "bk": node.bk,
             "bn": node.bn,
-            "use_kernel": node.use_kernel,
-            "interpret": node.interpret,
+            "out_axis": node.out_axis,
             "has_scales": node.scales is not None,
             "has_walk": node.walk is not None,
         }
@@ -722,6 +719,11 @@ def load_plan(base: str, dense_params) -> WeightPlan:
     meta = manifest["metadata"]
     cfg_d = dict(meta["plan_cfg"])
     cfg_d["rules"] = tuple(tuple(r) for r in cfg_d["rules"])
+    unknown = set(cfg_d) - {f.name for f in dataclasses.fields(PlanConfig)}
+    if unknown or any("out_axis" not in pm for pm in meta["packed"].values()
+                      if pm["repr"] == "packed"):
+        raise ValueError(f"plan cache written by another version "
+                         f"(plan config fields {sorted(unknown)})")
     cfg = PlanConfig(**cfg_d)
 
     def skeleton(path, leaf):
@@ -748,8 +750,7 @@ def load_plan(base: str, dense_params) -> WeightPlan:
                 shape=tuple(pm["shape"]),
                 bk=pm["bk"],
                 bn=pm["bn"],
-                use_kernel=pm["use_kernel"],
-                interpret=pm["interpret"],
+                out_axis=pm["out_axis"],
             )
         return leaf
 
@@ -805,7 +806,8 @@ def compress(params, cfg: PlanConfig = PlanConfig(), *, axes=None) -> WeightPlan
             packed = quantize_leaf(leaf)
         else:
             pc = cfg if q == cfg.q_prune else dataclasses.replace(cfg, q_prune=q)
-            packed = pack_block_sparse(leaf, pc, quant=(kind == "quant_sparse"))
+            packed = pack_block_sparse(leaf, pc, quant=(kind == "quant_sparse"),
+                                       out_axis=ax[-1] if ax else None)
         leaves[ps] = _leaf_stats(
             ps, kind, leaf, packed, axes=tuple(ax) if ax else ())
         by_path[ps] = packed
@@ -879,18 +881,32 @@ def _apply_fused_pair(x, g: PackedLinear, u: PackedLinear, activation: str):
     K, N = g.shape
     lead = x.shape[:-1]
     x2 = x.reshape(-1, K)
-    if g.use_kernel or u.use_kernel:
+    if _kernel_on(g) or _kernel_on(u):
         from repro.kernels import ops
 
-        y = ops.fused_gate_up(
-            x2, g.to_block_sparse(), u.to_block_sparse(),
-            gate_scales=g.scales, up_scales=u.scales,
-            activation=activation,
-            interpret=True if (g.interpret or u.interpret) else None,
-        )
+        out = sl.mesh_axes(g.out_axis, N // g.bn)
+        rows = sl.mesh_axes("batch", x2.shape[0])
+
+        def local(x2, gb, gr, gc, gs, ub, ur, uc, us):
+            return ops.fused_gate_up(
+                x2, _local_sparse(g, gb, gr, gc), _local_sparse(u, ub, ur, uc),
+                gate_scales=gs, up_scales=us, activation=activation)
+
+        col = (P(out, None, None), P(out, None), P(out), P(out))
+        y = sl.kernel_call(
+            local,
+            (x2, g.blocks, g.block_rows, g.counts, g.scales,
+             u.blocks, u.block_rows, u.counts, u.scales),
+            in_specs=(P(rows, None),) + col + col, out_specs=P(rows, out))
     else:
         y = _GATE_ACTS[activation](_packed_ref_matmul(x2, g)) * _packed_ref_matmul(x2, u)
     return y.astype(x.dtype).reshape(*lead, N)
+
+
+def _kernel_on(w: PackedLinear) -> bool:
+    if w.use_kernel is not None:
+        return w.use_kernel
+    return jax.default_backend() == "tpu"
 
 
 def apply_linear(x: jax.Array, w) -> jax.Array:
@@ -932,7 +948,7 @@ def _apply_packed(x, w: PackedLinear):
     K, N = w.shape
     lead = x.shape[:-1]
     x2 = x.reshape(-1, K)
-    if w.use_kernel:
+    if _kernel_on(w):
         y = _packed_kernel_matmul(x2, w)
     else:
         y = _packed_ref_matmul(x2, w)
@@ -964,16 +980,38 @@ def _packed_ref_matmul(x2: jax.Array, w: PackedLinear) -> jax.Array:
     return y
 
 
+def _local_sparse(w: PackedLinear, blocks, block_rows, counts) -> BlockSparse:
+    """The BlockSparse view of one shard's block columns of ``w``."""
+    return BlockSparse(
+        blocks=blocks, block_rows=block_rows, counts=counts,
+        shape=(w.shape[0], block_rows.shape[0] * w.bn),
+        cfg=BlockPruneConfig(bk=w.bk, bn=w.bn),
+    )
+
+
 def _packed_kernel_matmul(x2: jax.Array, w: PackedLinear) -> jax.Array:
     """Pallas block-sparse kernel path: pruned blocks are never read from HBM
     and never enter the MXU (ops wrapper pads the row dim / picks interpret
     mode off-TPU).  With a pack-time walk the multi-column double-buffered
     kernel runs; legacy PackedLinears without one fall back to the
-    per-column sweep."""
+    per-column sweep.
+
+    Under a mesh the call runs per shard (``shardlib.kernel_call``): each
+    chip multiplies its own block columns.  The walk indexes the global
+    column list, so a column-sharded call takes the per-column kernel."""
     from repro.kernels import ops
 
-    return ops.block_sparse_matmul(
-        x2, w.to_block_sparse(), scales=w.scales,
-        interpret=True if w.interpret else None,
-        walk=w.walk,
-    )
+    out = sl.mesh_axes(w.out_axis, w.shape[1] // w.bn)
+    rows = sl.mesh_axes("batch", x2.shape[0])
+    walk = w.walk if out is None else None
+
+    def local(x2, blocks, block_rows, counts, scales, walk):
+        return ops.block_sparse_matmul(
+            x2, _local_sparse(w, blocks, block_rows, counts),
+            scales=scales, walk=walk)
+
+    return sl.kernel_call(
+        local, (x2, w.blocks, w.block_rows, w.counts, w.scales, walk),
+        in_specs=(P(rows, None), P(out, None, None), P(out, None), P(out),
+                  P(out), P()),
+        out_specs=P(rows, out))

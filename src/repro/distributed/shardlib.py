@@ -370,6 +370,45 @@ def shard_pinned(x: jax.Array, *logical: Optional[str]) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
+def mesh_axes(logical: Optional[str], size: int):
+    """The PartitionSpec entry the active (mesh, rules) give a dimension of
+    ``size`` named ``logical``: mesh axis name(s), or None where there is
+    no mesh, no mapping, or the mapping does not divide ``size``.  Kernel
+    wrappers resolve each logical axis once this way, so every operand
+    that carries it shards identically."""
+    s = _get()
+    if not s or logical is None:
+        return None
+    mesh, rules = s[-1]
+    return _resolve(mesh, rules, (logical,), (size,))[0]
+
+
+def kernel_call(fn, args: Sequence[Any], in_specs: Sequence[Any], out_specs):
+    """``fn(*args)``, run per shard under the active mesh.
+
+    A Pallas kernel lowers to one opaque TPU custom call that the SPMD
+    partitioner cannot split, so under a mesh each kernel call runs inside
+    ``shard_map`` on its operands' local shards (``in_specs`` and
+    ``out_specs`` are PartitionSpecs over the active mesh; operands held
+    otherwise are resharded to match).  ``None`` args pass through
+    unchanged.  Without a mesh this is a plain call."""
+    s = _get()
+    if not s:
+        return fn(*args)
+    present = [i for i, a in enumerate(args) if a is not None]
+
+    def local(*xs):
+        full = list(args)
+        for i, x in zip(present, xs):
+            full[i] = x
+        return fn(*full)
+
+    return jax.shard_map(
+        local, mesh=s[-1][0], in_specs=tuple(in_specs[i] for i in present),
+        out_specs=out_specs, check_vma=False,
+    )(*(args[i] for i in present))
+
+
 def spec_for(shape: Sequence[int], *logical: Optional[str], mesh: Optional[Mesh] = None,
              rules: Optional[dict] = None) -> P:
     """PartitionSpec for a parameter of `shape` with logical axes (used to

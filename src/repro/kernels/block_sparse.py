@@ -254,7 +254,7 @@ def block_sparse_matmul_mc(
 
     in_specs = [
         pl.BlockSpec((block_b, cfg.bk), x_index),
-        pl.BlockSpec(memory_space=pltpu.ANY),  # payload stays in HBM; DMA'd
+        pl.BlockSpec(memory_space=pl.ANY),  # payload stays in HBM; DMA'd
     ]
     operands = [x, sparse.blocks]
     if scales is not None:

@@ -199,10 +199,10 @@ def flash_attention(
 def _paged_decode_kernel(
     pt_ref,  # (B * P,) scalar prefetch: flattened page table
     pos_ref,  # (B,)    scalar prefetch: position of each sequence's q[:, 0]
-    q_ref,  # (1, T * G, hd) — rows interleave (query offset t, group g)
-    k_ref,  # (1, ps, 1, hd) one physical page, one kv head
+    q_ref,  # (1, KVH, T * G, hd) — rows interleave (query offset t, group g)
+    k_ref,  # (1, KVH, ps, hd) one physical page, every kv head
     v_ref,
-    *refs,  # [ks_ref (1, ps, 1), vs_ref], o_ref, m_ref, l_ref, acc_ref
+    *refs,  # [ks_ref (1, KVH, ps), vs_ref], o_ref, m_ref, l_ref, acc_ref
     pages_per_seq: int,
     page_size: int,
     kv_heads: int,
@@ -218,8 +218,8 @@ def _paged_decode_kernel(
     else:
         ks_ref = vs_ref = None
         o_ref, m_ref, l_ref, acc_ref = refs
+    b = pl.program_id(0)
     p = pl.program_id(1)
-    b = pl.program_id(0) // kv_heads
 
     @pl.when(p == 0)
     def _init():
@@ -227,18 +227,10 @@ def _paged_decode_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32) * scale  # (T*G, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (ps, hd)
-    if quantized_kv:
-        k = k * ks_ref[0].reshape(page_size, 1).astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (T*G, ps)
-    if softcap > 0.0:
-        s = jnp.tanh(s / softcap) * softcap
-
     pos = pos_ref[b]
-    kv_pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    rows = q_ref.shape[2]
+    kv_pos = p * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, page_size), 1)
     # per-query masking: row (t, g) is query position pos + t, and entries
     # beyond it — including every slot of logical pages the sequence has
     # not reached (their table entries point at the null page) — never
@@ -246,24 +238,40 @@ def _paged_decode_kernel(
     # pos from every query row.
     q_pos = pos
     if causal:
-        q_pos = pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // groups
+        q_pos = pos + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, page_size), 0) // groups
     mask = kv_pos <= q_pos
     if window > 0:
         mask &= kv_pos > q_pos - window
-    s = jnp.where(mask, s, -1e30)
 
-    m_prev = m_ref[...]  # (T*G, 1)
-    m_new = jnp.maximum(m_prev[:, 0], s.max(axis=1))[:, None]
-    alpha = jnp.exp(m_prev - m_new)
-    pexp = jnp.exp(s - m_new)  # (T*G, ps)
-    l_ref[...] = l_ref[...] * alpha + pexp.sum(axis=1)[:, None]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    if quantized_kv:
-        v = v * vs_ref[0].reshape(page_size, 1).astype(jnp.float32)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        pexp, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
+    # one page holds every kv head: each head's (ps, hd) tile is a whole
+    # trailing block, so the loads stay tile-aligned for any page size
+    for h in range(kv_heads):
+        q = q_ref[0, h].astype(jnp.float32) * scale  # (T*G, hd)
+        k = k_ref[0, h].astype(jnp.float32)  # (ps, hd)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # (T*G, ps)
+        if quantized_kv:
+            # int8 pages: the per-(slot, head) scale of K is a column scale
+            # of the scores, and V's folds into the probabilities
+            s = s * ks_ref[0, h : h + 1, :].astype(jnp.float32)
+        if softcap > 0.0:
+            s = jnp.tanh(s / softcap) * softcap
+        s = jnp.where(mask, s, -1e30)
+
+        m_prev = m_ref[h]  # (T*G, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        pexp = jnp.exp(s - m_new)  # (T*G, ps)
+        l_ref[h] = l_ref[h] * alpha + pexp.sum(axis=1, keepdims=True)
+        if quantized_kv:
+            pexp = pexp * vs_ref[0, h : h + 1, :].astype(jnp.float32)
+        v = v_ref[0, h].astype(jnp.float32)
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            pexp, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        m_ref[h] = m_new
 
     @pl.when(p == pages_per_seq - 1)
     def _finalize():
@@ -272,7 +280,7 @@ def _paged_decode_kernel(
 
 def paged_decode_attention(
     q: jax.Array,  # (B, T, H, hd) — T=1 decode, T=k+1 speculative verify
-    k_pages: jax.Array,  # (num_pages, page_size, KVH, hd)
+    k_pages: jax.Array,  # (num_pages, KVH, page_size, hd)
     v_pages: jax.Array,
     page_table: jax.Array,  # (B, pages_per_seq) int32
     pos: jax.Array,  # (B,) int32, position of q[:, 0]
@@ -280,40 +288,44 @@ def paged_decode_attention(
     causal: bool = True,
     window: int | None = None,
     softcap: float = 0.0,
-    k_scale_pages: jax.Array | None = None,  # (num_pages, page_size, KVH)
+    k_scale_pages: jax.Array | None = None,  # (num_pages, KVH, page_size)
     v_scale_pages: jax.Array | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Single-pass multi-query attention against the paged KV cache.
 
-    Grid (B * KVH, pages_per_seq), pages innermost: the online-softmax
-    (m, l, acc) statistics live in VMEM scratch across each sequence's page
-    sweep, and the K/V page for step (bh, p) is addressed through the
+    Grid (B, pages_per_seq), pages innermost: the online-softmax (m, l, acc)
+    statistics of every kv head live in VMEM scratch across each sequence's
+    page sweep, and the K/V page for step (b, p) is addressed through the
     prefetched page table — pages a sequence doesn't own are never fetched
-    into VMEM (the null page rides on masked positions only).  All T query
-    positions fold into the q-tile rows as (t, group) pairs, so each page
-    is DMA'd exactly once per step and scored against every query while it
-    sits in VMEM — the verify step's page-stream cost is independent of T.
-    Row t's causal mask is ``kv_pos <= pos + t`` (entries the verify step
-    already wrote at positions > pos + t mask out); ``causal=False`` gives
-    every row the full [0, pos] view (enc-dec cross-attention against a
-    static encoder pool).  The int8 scale pools select dequant-on-load,
-    mirroring the contiguous kernel.
+    into VMEM (the null page rides on masked positions only).  A grid step
+    fetches the page for all kv heads at once: pools are head-major
+    ``(num_pages, KVH, page_size, hd)``, so each head's ``(page_size, hd)``
+    tile spans the array's two trailing dims and satisfies the TPU's block
+    tiling rule whatever the page size.  All T query positions fold into
+    the q-tile rows as (t, group) pairs, so each page is DMA'd exactly once
+    per step and scored against every query while it sits in VMEM — the
+    verify step's page-stream cost is independent of T.  Row t's causal
+    mask is ``kv_pos <= pos + t`` (entries the verify step already wrote
+    at positions > pos + t mask out); ``causal=False`` gives every row the
+    full [0, pos] view (enc-dec cross-attention against a static encoder
+    pool).  The int8 scale pools select dequant-on-load, mirroring the
+    contiguous kernel.
     """
     B, T, H, hd = q.shape
-    num_pages, page_size, KVH, _ = k_pages.shape
+    num_pages, KVH, page_size, _ = k_pages.shape
     P = page_table.shape[1]
     G = H // KVH
     quantized_kv = k_scale_pages is not None
     assert (k_scale_pages is None) == (v_scale_pages is None)
     scale = 1.0 / math.sqrt(hd)
 
-    # fold (B, KVH) into the grid axis and (T, G) into the q-tile rows:
-    # row t * G + g of sequence-head (b, kvh) is query position pos[b] + t
+    # (T, G) fold into the q-tile rows: row t * G + g of kv head kvh is
+    # query position pos[b] + t
     qf = (
         q.reshape(B, T, KVH, G, hd)
         .transpose(0, 2, 1, 3, 4)
-        .reshape(B * KVH, T * G, hd)
+        .reshape(B, KVH, T * G, hd)
     )
     pt_flat = page_table.reshape(-1).astype(jnp.int32)
 
@@ -324,41 +336,41 @@ def paged_decode_attention(
         quantized_kv=quantized_kv,
     )
 
-    def q_index(bh, p, pt, pos_s):
-        return (bh, 0, 0)
+    def q_index(b, p, pt, pos_s):
+        return (b, 0, 0, 0)
 
-    def kv_index(bh, p, pt, pos_s):
-        return (pt[(bh // KVH) * P + p], 0, bh % KVH, 0)
+    def kv_index(b, p, pt, pos_s):
+        return (pt[b * P + p], 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, T * G, hd), q_index),
-        pl.BlockSpec((1, page_size, 1, hd), kv_index),
-        pl.BlockSpec((1, page_size, 1, hd), kv_index),
+        pl.BlockSpec((1, KVH, T * G, hd), q_index),
+        pl.BlockSpec((1, KVH, page_size, hd), kv_index),
+        pl.BlockSpec((1, KVH, page_size, hd), kv_index),
     ]
     operands = [qf, k_pages, v_pages]
     if quantized_kv:
-        def sc_index(bh, p, pt, pos_s):
-            return (pt[(bh // KVH) * P + p], 0, bh % KVH)
+        def sc_index(b, p, pt, pos_s):
+            return (pt[b * P + p], 0, 0)
 
-        sc_spec = pl.BlockSpec((1, page_size, 1), sc_index)
+        sc_spec = pl.BlockSpec((1, KVH, page_size), sc_index)
         in_specs += [sc_spec, sc_spec]
         operands += [k_scale_pages, v_scale_pages]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B * KVH, P),
+        grid=(B, P),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, T * G, hd), q_index),
+        out_specs=pl.BlockSpec((1, KVH, T * G, hd), q_index),
         scratch_shapes=[
-            pltpu.VMEM((T * G, 1), jnp.float32),
-            pltpu.VMEM((T * G, 1), jnp.float32),
-            pltpu.VMEM((T * G, hd), jnp.float32),
+            pltpu.VMEM((KVH, T * G, 1), jnp.float32),
+            pltpu.VMEM((KVH, T * G, 1), jnp.float32),
+            pltpu.VMEM((KVH, T * G, hd), jnp.float32),
         ],
     )
     of = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * KVH, T * G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KVH, T * G, hd), q.dtype),
         interpret=interpret,
     )(pt_flat, pos.astype(jnp.int32), *operands)
     return (

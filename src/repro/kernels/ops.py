@@ -284,8 +284,12 @@ def cross_decode_attention(
     kp = _pad_dim(k, 1, page_size)
     vp = _pad_dim(v, 1, page_size)
     P = kp.shape[1] // page_size
-    k_pool = kp.reshape(B * P, page_size, KVH, hd)
-    v_pool = vp.reshape(B * P, page_size, KVH, hd)
+
+    def pool(x):  # (B, P * ps, KVH, hd) -> head-major (B * P, KVH, ps, hd)
+        return x.reshape(B, P, page_size, KVH, hd).swapaxes(2, 3).reshape(
+            B * P, KVH, page_size, hd)
+
+    k_pool, v_pool = pool(kp), pool(vp)
     # identity table: sequence b's logical page p is physical page b*P + p
     table = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
     pos = jnp.full((B,), Sf - 1, dtype=jnp.int32)

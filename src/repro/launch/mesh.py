@@ -12,20 +12,28 @@ import dataclasses
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
 
 from repro.distributed import shardlib as sl
+
+
+def _mesh(shape, axes):
+    """A mesh whose axes the partitioner may shard over freely: the model
+    code annotates logical axes (shardlib) and leaves propagation to GSPMD,
+    which needs ``Auto`` axes (``jax.make_mesh`` defaults to ``Explicit``)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this process has — used by tests/examples on CPU."""
     n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return _mesh((1, n), ("data", "model"))
 
 
 def make_serving_mesh(spec: str):
@@ -49,7 +57,7 @@ def make_serving_mesh(spec: str):
         raise ValueError(
             f"--mesh {spec} needs {d * m} devices, have {len(jax.devices())} "
             f"(hint: XLA_FLAGS=--xla_force_host_platform_device_count=N)")
-    return jax.make_mesh((d, m), ("data", "model"))
+    return _mesh((d, m), ("data", "model"))
 
 
 def rules_for(cfg, shape=None, *, zero_opt: bool = True,

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import time
 import warnings
 
@@ -38,6 +39,7 @@ from repro.core.perf_model import paged_pool_pages
 from repro.core.weight_plan import PlanConfig, load_plan, save_plan
 from repro.distributed import shardlib as sl
 from repro.launch import mesh as M
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import (
     get_api,
     kv_bytes_per_token,
@@ -102,6 +104,18 @@ def _build_plan(api, cfg, params, pc: PlanConfig, cache_dir: str | None):
         save_plan(cache_dir, plan)
         print(f"[serve] plan cached to {cache_dir}")
     return plan
+
+
+def _init_params(api, cfg, seed: int, mesh, rules):
+    """Random weights from ``seed``, created under their shardings when a
+    mesh is given (no device ever holds the whole dense model)."""
+    init = functools.partial(api.init_params, cfg)
+    key = jax.random.key(seed)
+    if mesh is None:
+        return jax.jit(init)(key)
+    shardings = sl.tree_shardings(
+        jax.eval_shape(init, key), api.param_axes(cfg), mesh=mesh, rules=rules)
+    return jax.jit(init, out_shardings=shardings)(key)
 
 
 def _parse_mix(spec: str, ap) -> list:
@@ -194,6 +208,7 @@ def _main_mixed(args, ap):
           f"{agg.decode_tokens} tokens "
           f"({agg.decode_tokens / max(dt, 1e-9):.1f} tok/s on this host), "
           f"{engine.tick} ticks, page audit clean")
+    return engine, stats
 
 
 def main(argv=None):
@@ -289,6 +304,7 @@ def main(argv=None):
                          "(serving/loadgen format; takes precedence over "
                          "--arrival-rate)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.workload_mix:
         for flag, ok in (("--arch", not args.arch),
@@ -330,7 +346,9 @@ def main(argv=None):
               f"{tuned['accuracy']['budget']:.1%} at max "
               f"q={tuned['accuracy']['max_q']:.2f}")
     api = get_api(cfg)
-    params = api.init_params(cfg, jax.random.key(args.seed))
+    mesh = M.make_serving_mesh(args.mesh)
+    rules = M.rules_for(cfg, None, mesh=mesh) if mesh is not None else None
+    params = _init_params(api, cfg, args.seed, mesh, rules)
     kv_dtype = "int8" if args.kv_dtype == "int8" else None
     if kv_dtype and not supports_int8_kv(cfg):
         kv_dtype = None  # engine would warn and serve fp: log the fp budget
@@ -342,8 +360,6 @@ def main(argv=None):
            if paged else args.max_len)
     kv_tok = kv_bytes_per_token(cfg, jax.numpy.int8 if kv_dtype else None,
                                 context_len=ctx)
-    mesh = M.make_serving_mesh(args.mesh)
-    rules = M.rules_for(cfg, None, mesh=mesh) if mesh is not None else None
     data_parallel, model_parallel, kv_parallel = sl.parallelism_degrees(
         mesh, rules if rules is not None else sl.DEFAULT_RULES,
         int(getattr(cfg, "n_kv_heads", 0) or 0))
@@ -376,18 +392,21 @@ def main(argv=None):
           f"machine-balance n_opt={_fmt_nopt(sizer.n_opt)} per model group"
           + (f" (x{data_parallel} data replicas for the global batch)"
              if data_parallel > 1 else "")
-          + f" (TPU v5e constants, kv={kv_tok:.0f} B/tok @ ctx {ctx})")
+          + f" (modeled with TPU v5e peaks, kv={kv_tok:.0f} B/tok @ ctx "
+            f"{ctx}; serving on {jax.devices()[0].device_kind})")
 
     plan = None
     if tuned is not None:
         plan = _build_plan(api, cfg, params, AT.plan_config(tuned),
                            args.plan_cache)
-        params = plan.params
     elif args.compress != "none":
         plan = _build_plan(api, cfg, params, PlanConfig(
             default=args.compress, q_prune=args.q_prune,
             bk=args.block, bn=args.block,
         ), args.plan_cache)
+    if plan is not None:
+        if mesh is not None:
+            plan = plan.placed(mesh, rules)
         params = plan.params
 
     pool_pages = args.pool_pages
@@ -527,6 +546,7 @@ def main(argv=None):
               f"{stats.retried} retries, {stats.fallback_ticks} degraded "
               f"ticks" + (f"; degraded: {engine.degraded}"
                           if engine.degraded else ""))
+    return engine, stats
 
 
 if __name__ == "__main__":

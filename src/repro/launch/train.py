@@ -28,6 +28,7 @@ from repro.data import LMDataConfig, synthetic_lm_batch
 from repro.distributed import shardlib as sl
 from repro.distributed.fault import RestartSupervisor, StragglerDetector
 from repro.launch import mesh as M
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import get_api
 from repro.training import optimizer as O
 from repro.training.trainer import make_train_step
@@ -165,6 +166,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     out = run(args)
     print(f"[train] done; final loss {out['final_loss']:.4f}")
 

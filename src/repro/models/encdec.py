@@ -203,7 +203,7 @@ def init_cache(cfg, batch: int, length: int, dtype=jnp.bfloat16, kv_dtype=None,
     targets the long-context transformer families.
 
     ``page_size`` selects the paged layout: the decoder self-attn cache
-    becomes (Ld, num_pages, ps, KVH, hd) pools addressed through
+    becomes (Ld, num_pages, KVH, ps, hd) pools addressed through
     ``page_table``, exactly like the transformer families — and the static
     encoder cross K/V becomes a first-class paged resource too: per-layer
     ``x`` pools addressed through ``xpage_table``, so a request's encoder
@@ -216,7 +216,7 @@ def init_cache(cfg, batch: int, length: int, dtype=jnp.bfloat16, kv_dtype=None,
     Ld = cfg.n_layers
     if page_size is not None:
         ps = int(page_size)
-        z = jnp.zeros((Ld, num_pages, ps, KVH, hd), dtype)
+        z = jnp.zeros((Ld, num_pages, KVH, ps, hd), dtype)
         return {
             "dec": {"k_pages": z, "v_pages": z},
             "x": {"k_pages": z, "v_pages": z},
@@ -238,7 +238,7 @@ _XKV_AXES = sl.register_cache_kind(
 # (kv_heads tensor-parallel, page axes replicated); the frame page table is
 # host-owned per replica like the decoder's.
 _XKV_PAGES_AXES = sl.register_cache_kind(
-    "encdec.xkv_pages", (None, None, "kv_heads", None),
+    "encdec.xkv_pages", (None, "kv_heads", None, None),
     positional=True, paged=True, family="encdec")
 _XPAGE_TABLE_AXES = sl.register_cache_kind(
     "encdec.xpage_table", ("batch", None),

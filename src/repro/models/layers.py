@@ -20,6 +20,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core.weight_plan import apply_gate_up, apply_linear
 from repro.core import weight_plan as _wp
@@ -687,8 +689,10 @@ def attn_cache_axes(quantized: bool = False):
 # paged KV cache (global pool of fixed-size pages + per-sequence page table)
 # ---------------------------------------------------------------------------
 #
-# Layout: pools are (num_pages, page_size, KVH, hd) per layer — the batch
-# axis is gone; sequences own *pages*, assigned by the host-side allocator
+# Layout: pools are head-major (num_pages, KVH, page_size, hd) per layer (the
+# scale pools drop hd) so one head's page is a whole trailing (page_size, hd)
+# tile — the block shape the TPU kernel can fetch for any page size.  The
+# batch axis is gone; sequences own *pages*, assigned by the host-side allocator
 # (serving/paged.py), and the int32 page table (B, pages_per_seq) maps each
 # slot's logical page index to a physical page.  Logical addressing is
 # position-identity (position p lives at page p // ps, slot p % ps): no ring
@@ -703,10 +707,10 @@ def init_paged_attn_cache(cfg, num_pages: int, page_size: int, dtype=jnp.bfloat1
     composing the paged layout with the halved int8 cache stream."""
     KVH, hd = cfg.n_kv_heads, cfg.hd
     if jnp.dtype(dtype) == jnp.int8:
-        z = jnp.zeros((num_pages, page_size, KVH, hd), jnp.int8)
-        s = jnp.zeros((num_pages, page_size, KVH), jnp.float32)
+        z = jnp.zeros((num_pages, KVH, page_size, hd), jnp.int8)
+        s = jnp.zeros((num_pages, KVH, page_size), jnp.float32)
         return {"k_pages": z, "v_pages": z, "k_scale_pages": s, "v_scale_pages": s}
-    z = jnp.zeros((num_pages, page_size, KVH, hd), dtype)
+    z = jnp.zeros((num_pages, KVH, page_size, hd), dtype)
     return {"k_pages": z, "v_pages": z}
 
 
@@ -716,10 +720,10 @@ def init_paged_attn_cache(cfg, num_pages: int, page_size: int, dtype=jnp.bfloat1
 # the decode gather never crosses chips).  The page axes stay replicated:
 # the table maps any slot to any physical page.
 _KV_PAGES_AXES = sl.register_cache_kind(
-    "attn.kv_pages", (None, None, "kv_heads", None),
+    "attn.kv_pages", (None, "kv_heads", None, None),
     positional=True, paged=True)
 _KV_SCALE_PAGES_AXES = sl.register_cache_kind(
-    "attn.kv_scale_pages", (None, None, "kv_heads"),
+    "attn.kv_scale_pages", (None, "kv_heads", None),
     positional=True, paged=True)
 
 
@@ -732,8 +736,8 @@ def paged_attn_cache_axes(quantized: bool = False):
 
 
 def paged_cache_update(
-    pool: jax.Array,  # (num_pages, page_size, ...) K/V or scale pool
-    new: jax.Array,  # (B, T, ...) this step's entries (T=1 classic decode)
+    pool: jax.Array,  # (num_pages, KVH, page_size, ...) K/V or scale pool
+    new: jax.Array,  # (B, T, KVH, ...) this step's entries (T=1 classic decode)
     page_table: jax.Array,  # (B, pages_per_seq) int32
     pos: jax.Array,  # (B,) absolute position of new[:, 0]
 ) -> jax.Array:
@@ -747,17 +751,30 @@ def paged_cache_update(
     the same holds for speculative writes past a sequence's allocated
     pages, whose table entries are NULL_PAGE.
     """
-    page_size = pool.shape[1]
-    B, T = new.shape[:2]
+    page_size = pool.shape[2]
+    B, T, KVH = new.shape[:3]
     positions = pos[:, None] + jnp.arange(T)[None]  # (B, T)
     phys = page_table[jnp.arange(B)[:, None], positions // page_size]
-    return pool.at[phys, positions % page_size].set(new.astype(pool.dtype))
+    return pool.at[
+        phys[..., None], jnp.arange(KVH), (positions % page_size)[..., None]
+    ].set(new.astype(pool.dtype))
+
+
+def write_pages(pools: jax.Array, phys, off, vals: jax.Array) -> jax.Array:
+    """Host-side write into layer-stacked pools (L, num_pages, KVH, ps, ...):
+    entry i of ``vals`` (L, n, KVH, ...) lands at slot ``off[i]`` of page
+    ``phys[i]`` in every layer — how the engine copies a contiguous
+    prefill cache into a sequence's pages."""
+    phys = np.asarray(phys)[:, None]
+    off = np.asarray(off)[:, None]
+    return pools.at[:, phys, np.arange(pools.shape[2]), off].set(
+        vals.astype(pools.dtype))
 
 
 def gather_pages(pool: jax.Array, page_table: jax.Array) -> jax.Array:
-    """(num_pages, ps, ...) pool -> (B, pages_per_seq * ps, ...) view of each
-    sequence's logical cache, via the page table."""
-    g = pool[page_table]  # (B, P, ps, ...)
+    """(num_pages, KVH, ps, ...) pool -> (B, pages_per_seq * ps, KVH, ...)
+    view of each sequence's logical cache, via the page table."""
+    g = jnp.swapaxes(pool[page_table], 2, 3)  # (B, P, ps, KVH, ...)
     B, P, ps = g.shape[:3]
     return g.reshape((B, P * ps) + g.shape[3:])
 
@@ -791,14 +808,14 @@ def force_attention_kernel(value: Optional[bool]) -> Optional[bool]:
 
 def paged_decode_attention(
     q: jax.Array,  # (B, T, H, hd) — T=1 decode, T=k+1 speculative verify
-    k_pages: jax.Array,  # (num_pages, ps, KVH, hd)
+    k_pages: jax.Array,  # (num_pages, KVH, ps, hd)
     v_pages: jax.Array,
     page_table: jax.Array,  # (B, pages_per_seq) int32
     pos: jax.Array,  # (B,) position of q[:, 0]
     *,
     window: Optional[int] = None,
     softcap: float = 0.0,
-    k_scale_pages: Optional[jax.Array] = None,  # (num_pages, ps, KVH)
+    k_scale_pages: Optional[jax.Array] = None,  # (num_pages, KVH, ps)
     v_scale_pages: Optional[jax.Array] = None,
     use_kernel: Optional[bool] = None,  # None = kernel on TPU, gather elsewhere
 ) -> jax.Array:
@@ -833,11 +850,17 @@ def paged_decode_attention(
     if use_kernel:
         from repro.kernels import ops  # deferred: models stay importable solo
 
-        return ops.paged_decode_attention(
-            q, k_pages, v_pages, page_table, pos,
-            window=window, softcap=softcap,
-            k_scale_pages=k_scale_pages, v_scale_pages=v_scale_pages,
-        )
+        def kernel(q, k_pages, v_pages, page_table, pos, ks, vs):
+            return ops.paged_decode_attention(
+                q, k_pages, v_pages, page_table, pos, window=window,
+                softcap=softcap, k_scale_pages=ks, v_scale_pages=vs)
+
+        return _per_kv_head_shard(
+            kernel, q, k_pages.shape[1],
+            (k_pages, v_pages, page_table, pos, k_scale_pages, v_scale_pages),
+            lambda kv, b: (P(None, kv, None, None), P(None, kv, None, None),
+                           P(b, None), P(b), P(None, kv, None),
+                           P(None, kv, None)))
     kc = gather_pages(k_pages, page_table)
     vc = gather_pages(v_pages, page_table)
     ksc = vsc = None
@@ -847,6 +870,30 @@ def paged_decode_attention(
     return decode_attention(
         q, kc, vc, pos, window=window, softcap=softcap, k_scale=ksc, v_scale=vsc
     )
+
+
+def _per_kv_head_shard(kernel, q, kv_heads: int, kv_args, kv_specs):
+    """Run an attention kernel per shard under the active mesh.
+
+    ``q`` (B, T, H, hd) is viewed as (B, T, KVH, G, hd) so its heads shard
+    exactly with the kv heads they attend to (a kv_heads mapping the mesh
+    cannot divide replicates both); ``kv_specs(kv, b)`` gives the other
+    operands' PartitionSpecs from the resolved kv-head and batch mesh
+    axes.  Without a mesh this is a plain kernel call."""
+    B, T, H, hd = q.shape
+    G = H // kv_heads
+    kv = sl.mesh_axes("kv_heads", kv_heads)
+    b = sl.mesh_axes("batch", B)
+    spec = P(b, None, kv, None, None)
+
+    def local(q5, *rest):
+        Bl, _, kvl = q5.shape[:3]
+        return kernel(q5.reshape(Bl, T, kvl * G, hd), *rest).reshape(q5.shape)
+
+    o = sl.kernel_call(
+        local, (q.reshape(B, T, kv_heads, G, hd),) + tuple(kv_args),
+        (spec,) + kv_specs(kv, b), spec)
+    return o.reshape(B, T, H, hd)
 
 
 def cross_decode_attention(
@@ -873,7 +920,11 @@ def cross_decode_attention(
     if use_kernel:
         from repro.kernels import ops  # deferred: models stay importable solo
 
-        return ops.cross_decode_attention(q, xk, xv, softcap=softcap)
+        return _per_kv_head_shard(
+            lambda q, xk, xv: ops.cross_decode_attention(
+                q, xk, xv, softcap=softcap),
+            q, xk.shape[2], (xk, xv),
+            lambda kv, b: (P(b, None, kv, None),) * 2)
     return attention(q, xk, xv, causal=False, softcap=softcap)
 
 

@@ -153,8 +153,9 @@ from repro.models.api import (
     supports_int8_kv,
     supports_paged_kv,
 )
-from repro.models.layers import finite_rows
+from repro.models.layers import finite_rows, write_pages
 from repro.serving.config import EngineConfig, positional_state_gate
+from repro.serving.faultinject import FaultInjected
 from repro.serving.paged import (
     NULL_PAGE,
     PageAllocator,
@@ -1462,9 +1463,8 @@ class ServingEngine:
                 new = dict(entry)
                 for pk, ck in _PAGED_KEYS:
                     if pk in entry:
-                        vals = one[ck][:, 0, pos_w]
-                        new[pk] = entry[pk].at[:, phys, off].set(
-                            vals.astype(entry[pk].dtype))
+                        new[pk] = write_pages(
+                            entry[pk], phys, off, one[ck][:, 0, pos_w])
                 lst[i] = new
             else:
                 lst[i] = jax.tree.map(
@@ -1486,8 +1486,7 @@ class ServingEngine:
         x = self.cache["x"]
         new = dict(x)
         for pk, ck in (("k_pages", "xk"), ("v_pages", "xv")):
-            vals = cache1[ck][:, 0, pos_w]
-            new[pk] = x[pk].at[:, phys, off].set(vals.astype(x[pk].dtype))
+            new[pk] = write_pages(x[pk], phys, off, cache1[ck][:, 0, pos_w])
         self.cache["x"] = new
 
     def _free_slot_pages(self, slot: int):
@@ -1627,14 +1626,15 @@ class ServingEngine:
     def _run_decode(self, tokens, pos):
         """Run the ONE compiled decode step with the folded numeric guard;
         returns host (logits, per-slot finite flags).  Handles the
-        kernel-fault rung: a raising step on a paged engine degrades the
-        attention path to the pure-JAX reference and retries ONCE.
+        kernel-fault rung: an injected ``kernel_fault`` on a paged engine
+        degrades the attention path to the pure-JAX reference and retries
+        ONCE.  Any other exception propagates: a kernel the compiler
+        refuses, or one that fails at run time, is a fault of the served
+        path, not something to hide behind the reference datapath.
 
-        The retry is only safe because failures surface before the
-        donated cache buffers are consumed: the injected ``kernel_fault``
-        raises host-side ahead of the call, and real Pallas lowering
-        failures raise at trace/compile time — both leave ``self.cache``
-        intact for the reference-path retry."""
+        The retry is safe because the injected fault raises host-side
+        ahead of the call, leaving ``self.cache`` intact (the donated
+        buffers are never consumed)."""
         poison = self._poison_mask()
         fi = self.fault_injector
         try:
@@ -1642,7 +1642,7 @@ class ServingEngine:
                 fi.check_kernel(self.tick, "attention_kernel" in self.degraded)
             logits, ok, self.cache = self._decode(
                 self.params, self.cache, tokens, pos, poison)
-        except Exception as e:
+        except FaultInjected as e:
             if not self.paged or "attention_kernel" in self.degraded:
                 raise
             self._degrade_attention_kernel(str(e))

@@ -61,8 +61,9 @@ KINDS = (
 
 class FaultInjected(RuntimeError):
     """Raised by injection points that model a raising failure (dead draft,
-    kernel fault).  Deliberately a RuntimeError subclass: the engine's
-    recovery paths must not special-case injected faults vs real ones."""
+    kernel fault).  A RuntimeError subclass, so the draft rung handles it
+    like a real draft failure.  The attention-kernel rung degrades on this
+    exception only: a real kernel failure propagates to the caller."""
 
 
 @dataclasses.dataclass(frozen=True)

@@ -4,7 +4,7 @@ registry.
 The device side (``models/layers.init_paged_attn_cache`` /
 ``paged_decode_attention`` and the Pallas kernel in
 ``kernels/flash_attention``) sees only two things: per-layer page *pools*
-``(num_pages, page_size, KVH, hd)`` and one int32 *page table*
+``(num_pages, KVH, page_size, hd)`` and one int32 *page table*
 ``(max_batch, pages_per_seq)`` mapping each slot's logical page index to a
 physical page.  Everything about who owns which page lives here, on the
 host, so the compiled decode step stays a pure function of (params, cache,

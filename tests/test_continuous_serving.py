@@ -10,14 +10,13 @@ variants, decode never starving during a long chunked prefill, the
 per-tick prefill budget respected, mid-prefill preemption, open-loop
 determinism, and a chaos soak with zero page leaks.  Randomized
 arrival/finish/evict/cancel sequences run both as hypothesis properties
-(via ``_hypcompat``) and as deterministic seeded examples so the
-invariants hold on minimal images too.
+and as deterministic seeded examples.
 """
 
 import jax
 import numpy as np
 import pytest
-from _hypcompat import given, settings, st  # degrades to skips without hypothesis
+from hypothesis import given, settings, strategies as st
 
 import repro.configs as C
 from repro.core.batching import BatchSizer

@@ -7,7 +7,7 @@ import dataclasses
 
 import jax
 import pytest
-from _hypcompat import given, settings, st  # degrades to skips without hypothesis
+from hypothesis import given, settings, strategies as st
 
 import repro.configs as C
 import repro.serving.config as SC

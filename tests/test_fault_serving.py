@@ -14,7 +14,7 @@ import warnings
 import jax
 import numpy as np
 import pytest
-from _hypcompat import given, settings, st  # degrades to skips without hypothesis
+from hypothesis import given, settings, strategies as st
 
 import repro.configs as C
 from repro.core.batching import BatchSizer
@@ -603,6 +603,32 @@ class TestDegradationLadder:
                 assert r.state is RequestState.FINISHED
                 assert list(r.output) == expect[r.uid]
             assert eng.pages_in_use == 0
+        finally:
+            layers.force_attention_kernel(prev)
+
+    def test_real_kernel_error_propagates_without_degrading(self):
+        """Only an injected kernel fault takes the reference rung: any
+        other exception from the decode step (a kernel the compiler
+        refuses, a device error) reaches the caller, and the engine does
+        not report itself degraded."""
+        from repro.models import layers
+
+        cfg, api, params = _cfg_params()
+        prev = layers.force_attention_kernel(None)
+        try:
+            eng = ServingEngine(cfg, params, config=EngineConfig.of(
+                    max_len=64, max_batch=2, page_size=16))
+
+            def refused(*args):
+                raise RuntimeError("kernel refused by the compiler")
+
+            eng._decode = refused
+            for r in _reqs(cfg, 2, max_new=4):
+                eng.submit(r)
+            with pytest.raises(RuntimeError, match="refused"):
+                eng.run_until_done()
+            assert eng.degraded == {}
+            assert layers.force_attention_kernel(None) is None
         finally:
             layers.force_attention_kernel(prev)
 

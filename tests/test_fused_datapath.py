@@ -82,7 +82,7 @@ class TestMultiColumnKernel:
         w, x = _x((64, 96)), _x((8, 64))
         pc = dataclasses.replace(PC, min_size=64)
         p = WP.pack_block_sparse(w, pc, quant=True)
-        pk = dataclasses.replace(p, use_kernel=True, interpret=True)
+        pk = dataclasses.replace(p, use_kernel=True)
         np.testing.assert_allclose(
             np.asarray(WP.apply_linear(x, pk)),
             np.asarray(WP.apply_linear(x, p)), rtol=1e-5, atol=1e-4)
@@ -93,7 +93,7 @@ class TestMultiColumnKernel:
         w, x = _x((64, 96)), _x((8, 64))
         pc = dataclasses.replace(PC, min_size=64)
         pk = dataclasses.replace(
-            WP.pack_block_sparse(w, pc, quant=True), use_kernel=True, interpret=True)
+            WP.pack_block_sparse(w, pc, quant=True), use_kernel=True)
         assert pk.walk is not None
         y = jax.jit(WP.apply_linear)(x, pk)
         np.testing.assert_allclose(np.asarray(y), np.asarray(WP.apply_linear(x, pk)),
@@ -109,7 +109,7 @@ class TestMultiColumnKernel:
         x = _x((8, 64))
         for l in range(3):
             sl_ = jax.tree.map(lambda a: a[l], p)
-            sl_ = dataclasses.replace(sl_, use_kernel=True, interpret=True)
+            sl_ = dataclasses.replace(sl_, use_kernel=True)
             pl_ = WP.pack_block_sparse(ws[l], pc, quant=True)
             np.testing.assert_allclose(
                 np.asarray(WP.apply_linear(x, sl_)),
@@ -141,8 +141,8 @@ class TestFusedGateUp:
         g, u = self._pair(quant)
         x = _x((8, 64))
         two = WP._GATE_ACTS[act](WP.apply_linear(x, g)) * WP.apply_linear(x, u)
-        gk = dataclasses.replace(g, use_kernel=True, interpret=True)
-        uk = dataclasses.replace(u, use_kernel=True, interpret=True)
+        gk = dataclasses.replace(g, use_kernel=True)
+        uk = dataclasses.replace(u, use_kernel=True)
         one = WP.apply_gate_up(x, gk, uk, act)
         np.testing.assert_allclose(np.asarray(one), np.asarray(two),
                                    rtol=1e-4, atol=1e-4)
@@ -156,8 +156,8 @@ class TestFusedGateUp:
                                  quant=True)
         x = _x((8, 64))
         two = WP._GATE_ACTS["silu"](WP.apply_linear(x, g)) * WP.apply_linear(x, u)
-        gk = dataclasses.replace(g, use_kernel=True, interpret=True)
-        uk = dataclasses.replace(u, use_kernel=True, interpret=True)
+        gk = dataclasses.replace(g, use_kernel=True)
+        uk = dataclasses.replace(u, use_kernel=True)
         np.testing.assert_allclose(
             np.asarray(WP.apply_gate_up(x, gk, uk, "silu")), np.asarray(two),
             rtol=1e-4, atol=1e-4)
@@ -165,8 +165,8 @@ class TestFusedGateUp:
     def test_single_kernel_launch_in_jaxpr(self):
         """Acceptance: the fused quant_sparse FFN pair is ONE launch."""
         g, u = self._pair(quant=True)
-        gk = dataclasses.replace(g, use_kernel=True, interpret=True)
-        uk = dataclasses.replace(u, use_kernel=True, interpret=True)
+        gk = dataclasses.replace(g, use_kernel=True)
+        uk = dataclasses.replace(u, use_kernel=True)
         x = _x((8, 64))
         jaxpr = str(jax.make_jaxpr(lambda xx: WP.apply_gate_up(xx, gk, uk, "silu"))(x))
         assert jaxpr.count("pallas_call") == 1

@@ -39,13 +39,14 @@ from repro.serving.engine import Request, ServingEngine
 
 
 def _paged_copy_of(x, ps, num_pages, table):
-    """Pack a contiguous (B, S, ...) cache into (num_pages, ps, ...) pools
+    """Pack a contiguous (B, S, ...) cache into head-major (num_pages, KVH, ps, ...) pools
     laid out per ``table`` (mirrors tests/test_paged_cache.py)."""
     B, S = x.shape[:2]
-    pool = jnp.zeros((num_pages, ps) + x.shape[2:], x.dtype)
+    pool = jnp.zeros((num_pages, x.shape[2], ps) + x.shape[3:], x.dtype)
     for b in range(B):
         for lp in range(S // ps):
-            pool = pool.at[int(table[b, lp])].set(x[b, lp * ps : (lp + 1) * ps])
+            pool = pool.at[int(table[b, lp])].set(
+                jnp.swapaxes(x[b, lp * ps : (lp + 1) * ps], 0, 1))
     return pool
 
 

@@ -131,13 +131,14 @@ class TestPerfModelPaging:
 
 
 def _paged_copy_of(k, ps, num_pages, table):
-    """Pack a contiguous (B, S, ...) cache into (num_pages, ps, ...) pools
+    """Pack a contiguous (B, S, ...) cache into head-major (num_pages, KVH, ps, ...) pools
     laid out per ``table``."""
     B, S = k.shape[:2]
-    pool = jnp.zeros((num_pages, ps) + k.shape[2:], k.dtype)
+    pool = jnp.zeros((num_pages, k.shape[2], ps) + k.shape[3:], k.dtype)
     for b in range(B):
         for lp in range(S // ps):
-            pool = pool.at[int(table[b, lp])].set(k[b, lp * ps : (lp + 1) * ps])
+            pool = pool.at[int(table[b, lp])].set(
+                jnp.swapaxes(k[b, lp * ps : (lp + 1) * ps], 0, 1))
     return pool
 
 

@@ -3,7 +3,7 @@
 import math
 
 import pytest
-from _hypcompat import given, settings, st  # degrades to skips without hypothesis
+from hypothesis import given, settings, strategies as st
 
 from repro.core import perf_model as pm
 

@@ -63,7 +63,7 @@ class TestRegistry:
         assert paged["k_pages"] == sl.axes_for("attn.kv_pages")
         assert paged["v_scale_pages"] == sl.axes_for("attn.kv_scale_pages")
         # pools shard over the model axis on kv_heads; page axes replicated
-        assert sl.axes_for("attn.kv_pages")[2] == "kv_heads"
+        assert sl.axes_for("attn.kv_pages")[1] == "kv_heads"
         assert sl.axes_for("attn.kv_pages")[0] is None
 
     def test_page_table_in_transformer_cache_axes(self):

@@ -3,7 +3,7 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypcompat import given, settings, st  # degrades to skips without hypothesis
+from hypothesis import given, settings, strategies as st
 
 from repro.core import sparse_format as sf
 from repro.core.pruning import BlockPruneConfig, sparsity_target_mask

@@ -125,13 +125,14 @@ class TestSupportsSpecDecode:
 
 
 def _paged_copy_of(k, ps, num_pages, table):
-    """Pack a contiguous (B, S, ...) cache into (num_pages, ps, ...) pools
+    """Pack a contiguous (B, S, ...) cache into head-major (num_pages, KVH, ps, ...) pools
     laid out per ``table`` (mirrors tests/test_paged_cache.py)."""
     B, S = k.shape[:2]
-    pool = jnp.zeros((num_pages, ps) + k.shape[2:], k.dtype)
+    pool = jnp.zeros((num_pages, k.shape[2], ps) + k.shape[3:], k.dtype)
     for b in range(B):
         for lp in range(S // ps):
-            pool = pool.at[int(table[b, lp])].set(k[b, lp * ps : (lp + 1) * ps])
+            pool = pool.at[int(table[b, lp])].set(
+                jnp.swapaxes(k[b, lp * ps : (lp + 1) * ps], 0, 1))
     return pool
 
 
@@ -222,7 +223,8 @@ class TestMultiTokenDecode:
         """paged_cache_update with T entries == T single-entry scatters,
         including across a page boundary."""
         L, q, k, v, kp, vp, table, pos, ps = self._paged_setup(ps=4)
-        new = jax.random.normal(jax.random.key(9), (2, 3) + kp.shape[2:])
+        new = jax.random.normal(
+            jax.random.key(9), (2, 3, kp.shape[1]) + kp.shape[3:])
         seq = kp
         for t in range(3):
             seq = L.paged_cache_update(seq, new[:, t : t + 1], table, pos + t)
@@ -265,8 +267,8 @@ class TestMultiTokenDecode:
             jnp.asarray(rng.normal(size=(64, 128)), jnp.float32), pc, quant=True)
         u = WP.pack_block_sparse(
             jnp.asarray(rng.normal(size=(64, 128)), jnp.float32), pc, quant=True)
-        gk = dataclasses.replace(g, use_kernel=True, interpret=True)
-        uk = dataclasses.replace(u, use_kernel=True, interpret=True)
+        gk = dataclasses.replace(g, use_kernel=True)
+        uk = dataclasses.replace(u, use_kernel=True)
         x = jnp.asarray(rng.normal(size=(2, 4, 64)), jnp.float32)  # (B, k+1, d)
         jaxpr = str(jax.make_jaxpr(
             lambda xx: WP.apply_gate_up(xx, gk, uk, "silu"))(x))

@@ -80,7 +80,7 @@ class TestPackedDatapath:
         pc = dataclasses.replace(PC, q_prune=0.25, min_size=64)
         for quant in (False, True):
             p_ref = WP.pack_block_sparse(w, pc, quant=quant)
-            p_k = dataclasses.replace(p_ref, use_kernel=True, interpret=True)
+            p_k = dataclasses.replace(p_ref, use_kernel=True)
             np.testing.assert_allclose(
                 np.asarray(WP.apply_linear(x, p_k)),
                 np.asarray(WP.apply_linear(x, p_ref)),
