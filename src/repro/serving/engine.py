@@ -143,6 +143,7 @@ from typing import Callable, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core.batching import BatchSizer
 from repro.distributed import shardlib as sl
@@ -302,6 +303,10 @@ class EngineStats:
     # chunked-prefill decode-step calls.
     prefill_chunks: int = 0
     prefill_tokens: int = 0
+    # blocking device->host reads (``ServingEngine._to_host``): the decode
+    # step's result, each chunk's finite flag, each sampled row, each
+    # synchronous prefill and each draft step — one each
+    host_syncs: int = 0
 
     @property
     def mean_batch(self) -> float:
@@ -968,19 +973,20 @@ class ServingEngine:
         (first sampled token, cache1, logits-finite flag); a non-finite
         prefill row sends the request to the retry path instead of
         admitting a poisoned slot."""
-        cache1 = self.api.init_cache(
-            self.cfg, 1, self.max_len, self.dtype, kv_dtype=self.kv_dtype,
-            **self._spec_cache_kw(),
-        )
-        batch = {"tokens": jnp.asarray(tokens, jnp.int32)[None]}
-        for k, v in (req.extras or {}).items():
-            batch[k] = jnp.asarray(v)[None]
-        logits, cache1 = self._prefill1(self.params, batch, cache1)
-        self.stats.prefill_tokens += len(tokens)
-        row = logits[:, -1]
-        ok = bool(jnp.isfinite(row).all())
-        tok = self._sample(row, req.temperature)
-        return int(tok[0]), cache1, ok
+        with TraceAnnotation("engine.prefill", uid=req.uid, tokens=len(tokens)):
+            cache1 = self.api.init_cache(
+                self.cfg, 1, self.max_len, self.dtype, kv_dtype=self.kv_dtype,
+                **self._spec_cache_kw(),
+            )
+            batch = {"tokens": jnp.asarray(tokens, jnp.int32)[None]}
+            for k, v in (req.extras or {}).items():
+                batch[k] = jnp.asarray(v)[None]
+            logits, cache1 = self._prefill1(self.params, batch, cache1)
+            self.stats.prefill_tokens += len(tokens)
+            row = logits[:, -1]
+            ok, tok = self._to_host(jnp.isfinite(row).all(),
+                                    self._sample(row, req.temperature))
+        return int(tok[0]), cache1, bool(ok)
 
     def _draft_prefill_slot(self, slot: int, tokens: np.ndarray):
         """Fill the draft model's KV for this request's prefill tokens into
@@ -1001,7 +1007,9 @@ class ServingEngine:
                     tokens: np.ndarray, resumed: bool):
         if self.spec_active:
             try:
-                self._draft_prefill_slot(slot, tokens)
+                with TraceAnnotation("engine.prefill", uid=req.uid,
+                                     tokens=len(tokens)):
+                    self._draft_prefill_slot(slot, tokens)
             except Exception as e:  # dead draft at admission: rung 1
                 self._degrade_speculation(f"draft prefill failed: {e}")
         self.slot_req[slot] = req
@@ -1242,19 +1250,22 @@ class ServingEngine:
         explains why the overlap is a no-op rewrite); a non-finite chunk
         sends the request to the retry path like a poisoned inline prefill."""
         req = job.req
-        if job.cache1 is None:
-            job.cache1 = self.api.init_cache(
-                self.cfg, 1, self.max_len, self.dtype, kv_dtype=self.kv_dtype,
-                **self._spec_cache_kw(),
-            )
-        toks = jnp.asarray(job.tokens[start:stop], jnp.int32)[None]
-        pos = jnp.asarray([start], jnp.int32)
-        logits, ok, job.cache1 = self._decode(
-            self.params, job.cache1, toks, pos, jnp.zeros((1,), bool))
-        job.done = stop
-        self.stats.prefill_chunks += 1
-        self.stats.prefill_tokens += stop - start
-        if not bool(np.asarray(ok)[0]):
+        with TraceAnnotation("engine.prefill_chunk", uid=req.uid, slot=slot,
+                             start=start, stop=stop):
+            if job.cache1 is None:
+                job.cache1 = self.api.init_cache(
+                    self.cfg, 1, self.max_len, self.dtype,
+                    kv_dtype=self.kv_dtype, **self._spec_cache_kw(),
+                )
+            toks = jnp.asarray(job.tokens[start:stop], jnp.int32)[None]
+            pos = jnp.asarray([start], jnp.int32)
+            logits, ok, job.cache1 = self._decode(
+                self.params, job.cache1, toks, pos, jnp.zeros((1,), bool))
+            job.done = stop
+            self.stats.prefill_chunks += 1
+            self.stats.prefill_tokens += stop - start
+            (ok,) = self._to_host(ok)
+        if not bool(ok[0]):
             self._quarantine_slot(
                 slot, "non-finite prefill logits (chunked)")
             return
@@ -1279,28 +1290,31 @@ class ServingEngine:
         token, and hand the slot to ``_start_slot`` exactly like the
         synchronous admission path."""
         req = job.req
-        tok = int(self._sample(job.last_row, req.temperature)[0])
-        if self.paged:
-            ps = self.page_size
-            remaining = req.max_new_tokens - len(req.output or [])
-            try:
-                self._grow_slot_pages(slot, math.ceil((job.S + remaining) / ps))
-            except PoolExhausted as e:
-                self._quarantine_slot(
-                    slot, f"page pool exhausted at prefill completion: {e}")
-                return
-            self._write_slot_paged(slot, job.cache1,
-                                   start=job.shared_len, stop=job.S)
-            pages = self.slot_pages[slot]
-            self._table[slot, :] = NULL_PAGE
-            self._table[slot, : len(pages)] = pages
-            if self.registry is not None and job.prompt_key is not None:
-                self.registry.register(
-                    job.prompt_key, pages[: math.ceil(job.S / ps)])
-        else:
-            self._write_slot(slot, job.cache1)
-        self.slot_prefill[slot] = None
-        self._start_slot(slot, req, job.S, tok, job.tokens, job.resumed)
+        with TraceAnnotation("engine.prefill_finish", uid=req.uid, S=job.S):
+            (tok,) = self._to_host(self._sample(job.last_row, req.temperature))
+            tok = int(tok[0])
+            if self.paged:
+                ps = self.page_size
+                remaining = req.max_new_tokens - len(req.output or [])
+                try:
+                    self._grow_slot_pages(
+                        slot, math.ceil((job.S + remaining) / ps))
+                except PoolExhausted as e:
+                    self._quarantine_slot(
+                        slot, f"page pool exhausted at prefill completion: {e}")
+                    return
+                self._write_slot_paged(slot, job.cache1,
+                                       start=job.shared_len, stop=job.S)
+                pages = self.slot_pages[slot]
+                self._table[slot, :] = NULL_PAGE
+                self._table[slot, : len(pages)] = pages
+                if self.registry is not None and job.prompt_key is not None:
+                    self.registry.register(
+                        job.prompt_key, pages[: math.ceil(job.S / ps)])
+            else:
+                self._write_slot(slot, job.cache1)
+            self.slot_prefill[slot] = None
+            self._start_slot(slot, req, job.S, tok, job.tokens, job.resumed)
 
     def _grow_slot_pages(self, slot: int, n_pages: int):
         """In-flight prefill page growth: extend this slot's page list to
@@ -1540,39 +1554,40 @@ class ServingEngine:
         slots that remain live: a slot whose COW copy cannot be allocated
         (pool pressure, injected failure) is quarantined to the retry path
         instead of crashing the batch."""
-        ps = self.page_size
-        ok_live: List[int] = []
-        for slot in live:
-            first = int(self.slot_pos[slot]) // ps
-            last = (int(self.slot_pos[slot]) + span) // ps
-            # pages past the allocated range map to NULL_PAGE (speculative
-            # overrun): nothing to privatize there, the null page absorbs
-            try:
-                for lp in range(
-                        first, min(last, len(self.slot_pages[slot]) - 1) + 1):
-                    self._ensure_private(slot, lp)
-            except PoolExhausted as e:
-                self._quarantine_slot(
-                    slot, f"copy-on-write allocation failed: {e}")
-                continue
-            ok_live.append(slot)
-        table = jnp.asarray(self._table)
-        if self.mesh is not None:
-            # the table is host-owned per replica: commit it to its
-            # registered layout so the compiled step never resharding-
-            # guesses (the mapping is identical on every model chip)
-            table = jax.device_put(table, sl.named_sharding(
-                self.mesh, table.shape, *sl.axes_for("page_table"),
-                rules=self.rules))
-        self.cache["page_table"] = table
-        if self._xtable is not None:
-            xtable = jnp.asarray(self._xtable)
+        with TraceAnnotation("engine.publish_table", live=len(live)):
+            ps = self.page_size
+            ok_live: List[int] = []
+            for slot in live:
+                first = int(self.slot_pos[slot]) // ps
+                last = (int(self.slot_pos[slot]) + span) // ps
+                # pages past the allocated range map to NULL_PAGE (speculative
+                # overrun): nothing to privatize there, the null page absorbs
+                try:
+                    for lp in range(
+                            first, min(last, len(self.slot_pages[slot]) - 1) + 1):
+                        self._ensure_private(slot, lp)
+                except PoolExhausted as e:
+                    self._quarantine_slot(
+                        slot, f"copy-on-write allocation failed: {e}")
+                    continue
+                ok_live.append(slot)
+            table = jnp.asarray(self._table)
             if self.mesh is not None:
-                xtable = jax.device_put(xtable, sl.named_sharding(
-                    self.mesh, xtable.shape,
-                    *sl.axes_for("encdec.xpage_table"), rules=self.rules))
-            self.cache["xpage_table"] = xtable
-        return ok_live
+                # the table is host-owned per replica: commit it to its
+                # registered layout so the compiled step never resharding-
+                # guesses (the mapping is identical on every model chip)
+                table = jax.device_put(table, sl.named_sharding(
+                    self.mesh, table.shape, *sl.axes_for("page_table"),
+                    rules=self.rules))
+            self.cache["page_table"] = table
+            if self._xtable is not None:
+                xtable = jnp.asarray(self._xtable)
+                if self.mesh is not None:
+                    xtable = jax.device_put(xtable, sl.named_sharding(
+                        self.mesh, xtable.shape,
+                        *sl.axes_for("encdec.xpage_table"), rules=self.rules))
+                self.cache["xpage_table"] = xtable
+            return ok_live
 
     # -- degradation ladder ---------------------------------------------------
 
@@ -1623,9 +1638,32 @@ class ServingEngine:
                         poison[slot] = True
         return jnp.asarray(poison)
 
+    def _to_host(self, *arrays, span: bool = True) -> list:
+        """Blocking device->host read of ``arrays`` as numpy arrays, floats
+        as float32 (what the host's sampling math takes): one host sync,
+        counted in ``EngineStats.host_syncs``.  With ``span`` the wait for
+        the device (``engine.device_wait``) and the copy with its
+        conversion (``engine.fetch``) are profiler spans of their own; the
+        per-row reads of the sampling loop pass ``span=False`` so that the
+        loop stays one ``engine.sample`` span."""
+        self.stats.host_syncs += 1
+
+        def host(a):
+            f32 = jnp.issubdtype(a.dtype, jnp.floating)
+            return np.asarray(a, np.float32 if f32 else None)
+
+        if not span:
+            return [host(a) for a in arrays]
+        with TraceAnnotation("engine.device_wait"):
+            jax.block_until_ready(arrays)
+        with TraceAnnotation("engine.fetch",
+                             bytes=sum(a.nbytes for a in arrays)):
+            return [host(a) for a in arrays]
+
     def _run_decode(self, tokens, pos):
-        """Run the ONE compiled decode step with the folded numeric guard;
-        returns host (logits, per-slot finite flags).  Handles the
+        """Run the ONE compiled decode step with the folded numeric guard
+        over host ``tokens`` (B, T) and positions (B,); returns host
+        (logits, per-slot finite flags).  Handles the
         kernel-fault rung: an injected ``kernel_fault`` on a paged engine
         degrades the attention path to the pure-JAX reference and retries
         ONCE.  Any other exception propagates: a kernel the compiler
@@ -1635,20 +1673,25 @@ class ServingEngine:
         The retry is safe because the injected fault raises host-side
         ahead of the call, leaving ``self.cache`` intact (the donated
         buffers are never consumed)."""
-        poison = self._poison_mask()
-        fi = self.fault_injector
-        try:
-            if fi is not None:
-                fi.check_kernel(self.tick, "attention_kernel" in self.degraded)
-            logits, ok, self.cache = self._decode(
-                self.params, self.cache, tokens, pos, poison)
-        except FaultInjected as e:
-            if not self.paged or "attention_kernel" in self.degraded:
-                raise
-            self._degrade_attention_kernel(str(e))
-            logits, ok, self.cache = self._decode(
-                self.params, self.cache, tokens, pos, poison)
-        return np.asarray(logits, np.float32), np.asarray(ok)
+        B, T = tokens.shape
+        with TraceAnnotation("engine.decode", B=B, T=T):
+            tokens = jnp.asarray(tokens, jnp.int32)
+            pos = jnp.asarray(pos, jnp.int32)
+            poison = self._poison_mask()
+            fi = self.fault_injector
+            try:
+                if fi is not None:
+                    fi.check_kernel(self.tick,
+                                    "attention_kernel" in self.degraded)
+                logits, ok, self.cache = self._decode(
+                    self.params, self.cache, tokens, pos, poison)
+            except FaultInjected as e:
+                if not self.paged or "attention_kernel" in self.degraded:
+                    raise
+                self._degrade_attention_kernel(str(e))
+                logits, ok, self.cache = self._decode(
+                    self.params, self.cache, tokens, pos, poison)
+        return self._to_host(logits, ok)
 
     def step(self) -> int:
         """One engine tick: deadlines → admission → one batched decode
@@ -1656,32 +1699,36 @@ class ServingEngine:
         Returns the number of tokens committed this tick.  Every executed
         tick beats the watchdog; dropped ticks (fault injection) do not —
         which is exactly what ``HeartbeatMonitor`` stall detection keys
-        on."""
+        on.  Under ``jax.profiler`` the tick is one ``engine.step`` span
+        numbered ``self.tick``, its phases spans below it
+        (docs/architecture.md, "Spans and counters")."""
         self.tick += 1
-        fi = self.fault_injector
-        if fi is not None:
-            fi.begin_tick(self.tick)
-            if fi.drop_tick(self.tick):
-                return 0  # lost tick: no admission, no decode, no heartbeat
-        self._enforce_deadlines(self.clock())
-        self._admit()
-        if self.prefill_chunk is not None:
-            self._run_prefill_chunks()
-        live = self._decoding_slots()
-        if live:
-            if self.spec_active:
-                n = self._spec_step(live)
+        with StepTraceAnnotation("engine.step", step_num=self.tick):
+            fi = self.fault_injector
+            if fi is not None:
+                fi.begin_tick(self.tick)
+                if fi.drop_tick(self.tick):
+                    return 0  # lost tick: no admission, no decode, no heartbeat
+            self._enforce_deadlines(self.clock())
+            with TraceAnnotation("engine.admit", queued=len(self.queue)):
+                self._admit()
+            if self.prefill_chunk is not None:
+                self._run_prefill_chunks()
+            live = self._decoding_slots()
+            if live:
+                if self.spec_active:
+                    n = self._spec_step(live)
+                else:
+                    n = self._plain_step(live)
             else:
-                n = self._plain_step(live)
-        else:
-            n = 0
-        if self.audit_every_step:
-            self.audit_pages()
-        if self.watchdog is not None:
-            self.watchdog.beat(0)
-        if live and self.degraded:
-            self.stats.fallback_ticks += 1
-        return n
+                n = 0
+            if self.audit_every_step:
+                self.audit_pages()
+            if self.watchdog is not None:
+                self.watchdog.beat(0)
+            if live and self.degraded:
+                self.stats.fallback_ticks += 1
+            return n
 
     def _plain_step(self, live: List[int]) -> int:
         """One non-speculative decode tick over ``live``; returns committed
@@ -1690,24 +1737,28 @@ class ServingEngine:
             live = self._publish_table(live)
             if not live:
                 return 0
-        tokens = jnp.asarray(self.slot_last_tok, jnp.int32)[:, None]
-        pos = jnp.asarray(self.slot_pos, jnp.int32)
-        logits, ok = self._run_decode(tokens, pos)
+        logits, ok = self._run_decode(self.slot_last_tok[:, None],
+                                      self.slot_pos)
         rows = logits[:, 0]
         committed = 0
-        for slot in live:
-            req = self.slot_req[slot]
-            if not ok[slot]:
-                self._quarantine_slot(slot, "non-finite logits (quarantined)")
-                continue
-            tok = int(self._sample(rows[slot : slot + 1], req.temperature)[0])
-            req.output.append(tok)
-            self._emit(req, (tok,))
-            self.slot_last_tok[slot] = tok
-            self.slot_pos[slot] += 1
-            self.slot_remaining[slot] -= 1
-            committed += 1
-            self._finish_if_done(slot)
+        with TraceAnnotation("engine.sample", live=len(live)):
+            for slot in live:
+                req = self.slot_req[slot]
+                if not ok[slot]:
+                    self._quarantine_slot(
+                        slot, "non-finite logits (quarantined)")
+                    continue
+                (tok,) = self._to_host(
+                    self._sample(rows[slot : slot + 1], req.temperature),
+                    span=False)
+                tok = int(tok[0])
+                req.output.append(tok)
+                self._emit(req, (tok,))
+                self.slot_last_tok[slot] = tok
+                self.slot_pos[slot] += 1
+                self.slot_remaining[slot] -= 1
+                committed += 1
+                self._finish_if_done(slot)
         self.stats.decode_steps += 1
         self.stats.decode_tokens += committed
         return committed
@@ -1822,37 +1873,38 @@ class ServingEngine:
                 return 0
         tokens = np.concatenate(
             [np.asarray(self.slot_last_tok, np.int64)[:, None], drafts], axis=1)
-        arr, ok = self._run_decode(jnp.asarray(tokens, jnp.int32), pos0)
+        arr, ok = self._run_decode(tokens, pos0)
         # -- commit the accepted prefix (+ the guaranteed bonus token) --------
         committed_total = 0
         tick_accepted = 0
         proposed = 0
         n_verified = len(live)
-        for slot in live:
-            req = self.slot_req[slot]
-            if not ok[slot]:
-                self._quarantine_slot(
-                    slot, "non-finite verify logits (quarantined)")
-                continue
-            remaining = int(self.slot_remaining[slot])
-            a, toks = self._accept(
-                arr[slot], drafts[slot], draft_dists[slot], req.temperature)
-            c = min(len(toks), remaining)
-            toks = toks[:c]
-            self.stats.draft_proposed += k
-            proposed += k
-            # committed drafts: toks is [d_1..d_a, bonus]; truncation by
-            # remaining can clip the bonus, in which case ALL c committed
-            # tokens are accepted drafts (min handles both cases)
-            self.stats.draft_accepted += min(a, c)
-            tick_accepted += min(a, c)
-            req.output.extend(toks)
-            self._emit(req, toks)
-            self.slot_last_tok[slot] = toks[-1]
-            self.slot_pos[slot] += c
-            self.slot_remaining[slot] -= c
-            committed_total += c
-            self._finish_if_done(slot)
+        with TraceAnnotation("engine.sample", live=len(live)):
+            for slot in live:
+                req = self.slot_req[slot]
+                if not ok[slot]:
+                    self._quarantine_slot(
+                        slot, "non-finite verify logits (quarantined)")
+                    continue
+                remaining = int(self.slot_remaining[slot])
+                a, toks = self._accept(
+                    arr[slot], drafts[slot], draft_dists[slot], req.temperature)
+                c = min(len(toks), remaining)
+                toks = toks[:c]
+                self.stats.draft_proposed += k
+                proposed += k
+                # committed drafts: toks is [d_1..d_a, bonus]; truncation by
+                # remaining can clip the bonus, in which case ALL c committed
+                # tokens are accepted drafts (min handles both cases)
+                self.stats.draft_accepted += min(a, c)
+                tick_accepted += min(a, c)
+                req.output.extend(toks)
+                self._emit(req, toks)
+                self.slot_last_tok[slot] = toks[-1]
+                self.slot_pos[slot] += c
+                self.slot_remaining[slot] -= c
+                committed_total += c
+                self._finish_if_done(slot)
         self.stats.decode_steps += 1
         self.stats.decode_tokens += committed_total
         self.stats.verified_positions += n_verified * (k + 1)
@@ -1884,40 +1936,41 @@ class ServingEngine:
         real) or non-finite draft logits — per-slot masking cannot save a
         chain whose proposals feed later steps, so the caller degrades
         speculation instead."""
-        fi = self.fault_injector
-        if fi is not None:
-            fi.check_draft(self.tick)
-        drafts = np.zeros((B, k), np.int64)
-        draft_dists: List[Optional[np.ndarray]] = [None] * B
-        needs_dists = any(
-            self.slot_req[s].temperature > 0.0 for s in live)
-        if needs_dists:
-            draft_dists = [
-                np.zeros((k, self.cfg.vocab)) if self.slot_req[s] is not None
-                else None for s in range(B)]
-        cur = jnp.asarray(self.slot_last_tok, jnp.int32)[:, None]
-        # k+1 draft steps for k proposals: the last step writes the final
-        # draft's KV (its logits are discarded), so after a fully-accepted
-        # tick the draft cache has no hole at the new frontier - 1 — the
-        # accepted prefix is always exactly what the draft itself wrote.
-        for j in range(k + 1):
-            dlogits, self.draft_cache = self._draft_decode(
-                self.draft_params, self.draft_cache, cur, pos0 + j)
-            if j == k:
-                break
-            rows = np.asarray(dlogits[:, 0], np.float32)
-            if not np.isfinite(rows[live]).all():
-                raise FloatingPointError("non-finite draft logits")
-            nxt = np.asarray(self.slot_last_tok).copy()
-            for slot in live:
-                temp = self.slot_req[slot].temperature
-                tok, dist = self._host_sample(rows[slot], temp)
-                drafts[slot, j] = tok
-                nxt[slot] = tok
-                if dist is not None:
-                    draft_dists[slot][j] = dist
-            cur = jnp.asarray(nxt, jnp.int32)[:, None]
-        return drafts, draft_dists
+        with TraceAnnotation("engine.draft", k=k):
+            fi = self.fault_injector
+            if fi is not None:
+                fi.check_draft(self.tick)
+            drafts = np.zeros((B, k), np.int64)
+            draft_dists: List[Optional[np.ndarray]] = [None] * B
+            needs_dists = any(
+                self.slot_req[s].temperature > 0.0 for s in live)
+            if needs_dists:
+                draft_dists = [
+                    np.zeros((k, self.cfg.vocab)) if self.slot_req[s] is not None
+                    else None for s in range(B)]
+            cur = jnp.asarray(self.slot_last_tok, jnp.int32)[:, None]
+            # k+1 draft steps for k proposals: the last step writes the final
+            # draft's KV (its logits are discarded), so after a fully-accepted
+            # tick the draft cache has no hole at the new frontier - 1 — the
+            # accepted prefix is always exactly what the draft itself wrote.
+            for j in range(k + 1):
+                dlogits, self.draft_cache = self._draft_decode(
+                    self.draft_params, self.draft_cache, cur, pos0 + j)
+                if j == k:
+                    break
+                (rows,) = self._to_host(dlogits[:, 0])
+                if not np.isfinite(rows[live]).all():
+                    raise FloatingPointError("non-finite draft logits")
+                nxt = np.asarray(self.slot_last_tok).copy()
+                for slot in live:
+                    temp = self.slot_req[slot].temperature
+                    tok, dist = self._host_sample(rows[slot], temp)
+                    drafts[slot, j] = tok
+                    nxt[slot] = tok
+                    if dist is not None:
+                        draft_dists[slot][j] = dist
+                cur = jnp.asarray(nxt, jnp.int32)[:, None]
+            return drafts, draft_dists
 
     def run_until_done(self, max_ticks: int = 10000) -> EngineStats:
         for _ in range(max_ticks):
