@@ -304,9 +304,12 @@ class EngineStats:
     prefill_chunks: int = 0
     prefill_tokens: int = 0
     # blocking device->host reads (``ServingEngine._to_host``): the decode
-    # step's result, each chunk's finite flag, each sampled row, each
-    # synchronous prefill and each draft step — one each
+    # step's result, each chunk's finite flag, each tempered row's sample,
+    # each synchronous prefill and each draft step — one each
     host_syncs: int = 0
+    # plain-tick rows whose token came from the tick's one batched greedy
+    # choice (equals decode_tokens when every request is greedy)
+    batched_choices: int = 0
 
     @property
     def mean_batch(self) -> float:
@@ -1662,8 +1665,8 @@ class ServingEngine:
 
     def _run_decode(self, tokens, pos):
         """Run the ONE compiled decode step with the folded numeric guard
-        over host ``tokens`` (B, T) and positions (B,); returns host
-        (logits, per-slot finite flags).  Handles the
+        over host ``tokens`` (B, T) and positions (B,); returns the device
+        (logits, per-slot finite flags), not yet read.  Handles the
         kernel-fault rung: an injected ``kernel_fault`` on a paged engine
         degrades the attention path to the pure-JAX reference and retries
         ONCE.  Any other exception propagates: a kernel the compiler
@@ -1691,7 +1694,7 @@ class ServingEngine:
                 self._degrade_attention_kernel(str(e))
                 logits, ok, self.cache = self._decode(
                     self.params, self.cache, tokens, pos, poison)
-        return self._to_host(logits, ok)
+        return logits, ok
 
     def step(self) -> int:
         """One engine tick: deadlines → admission → one batched decode
@@ -1732,14 +1735,21 @@ class ServingEngine:
 
     def _plain_step(self, live: List[int]) -> int:
         """One non-speculative decode tick over ``live``; returns committed
-        tokens (quarantined slots commit nothing)."""
+        tokens (quarantined slots commit nothing).
+
+        The logits stay on the device.  Greedy rows take their tokens from
+        one batched argmax over the whole (B, 1, V) block, read with the
+        finite flags in the tick's one host sync ((B,) ids and flags, not
+        the logits).  A tempered row samples its own device row in slot
+        order, so the RNG splits fall as they would without greedy
+        neighbours; only such rows pay a read each."""
         if self.paged:
             live = self._publish_table(live)
             if not live:
                 return 0
         logits, ok = self._run_decode(self.slot_last_tok[:, None],
                                       self.slot_pos)
-        rows = logits[:, 0]
+        ids, ok = self._to_host(self._sample(logits, 0.0), ok)
         committed = 0
         with TraceAnnotation("engine.sample", live=len(live)):
             for slot in live:
@@ -1748,10 +1758,16 @@ class ServingEngine:
                     self._quarantine_slot(
                         slot, "non-finite logits (quarantined)")
                     continue
-                (tok,) = self._to_host(
-                    self._sample(rows[slot : slot + 1], req.temperature),
-                    span=False)
-                tok = int(tok[0])
+                if req.temperature <= 0.0:
+                    tok = int(ids[slot, 0])
+                    self.stats.batched_choices += 1
+                else:
+                    # sampled in float32, to which bf16 logits convert
+                    # exactly
+                    row = logits[slot : slot + 1, 0].astype(jnp.float32)
+                    (tok,) = self._to_host(
+                        self._sample(row, req.temperature), span=False)
+                    tok = int(tok[0])
                 req.output.append(tok)
                 self._emit(req, (tok,))
                 self.slot_last_tok[slot] = tok
@@ -1873,7 +1889,7 @@ class ServingEngine:
                 return 0
         tokens = np.concatenate(
             [np.asarray(self.slot_last_tok, np.int64)[:, None], drafts], axis=1)
-        arr, ok = self._run_decode(tokens, pos0)
+        arr, ok = self._to_host(*self._run_decode(tokens, pos0))
         # -- commit the accepted prefix (+ the guaranteed bonus token) --------
         committed_total = 0
         tick_accepted = 0

@@ -78,11 +78,18 @@ def _engine_spans(trace_dir):
     return out
 
 
+@pytest.mark.parametrize("tempered", [0, 1, 3])
 @pytest.mark.parametrize("chunked", [False, True])
-def test_a_decode_tick_syncs_once_plus_once_per_live_row(model, chunked):
+def test_a_decode_tick_syncs_once_plus_once_per_live_row(model, chunked,
+                                                         tempered):
+    """A plain tick reads the greedy rows' ids and every row's finite flag
+    in one sync; each tempered row adds the read of its own sample."""
     kw = dict(prefill_chunk=8, prefill_budget=64) if chunked else {}
     eng = _engine(model, **kw)
-    for r in _reqs(model, (5, 9, 12), max_new=10):
+    reqs = _reqs(model, (5, 9, 12), max_new=10)
+    for r in reqs[:tempered]:
+        r.temperature = 0.8
+    for r in reqs:
         eng.submit(r)
     while eng.queue or any(j is not None for j in eng.slot_prefill):
         eng.step()
@@ -90,7 +97,7 @@ def test_a_decode_tick_syncs_once_plus_once_per_live_row(model, chunked):
     assert len(live) == 3
     before = eng.stats.host_syncs
     eng.step()
-    assert eng.stats.host_syncs - before == 1 + len(live)
+    assert eng.stats.host_syncs - before == 1 + tempered
 
 
 def test_a_prefill_chunk_syncs_once(model):
@@ -101,10 +108,10 @@ def test_a_prefill_chunk_syncs_once(model):
         assert eng.stats.prefill_chunks == n
         assert eng.stats.host_syncs == n
     # the last chunk, the first token's sample, then the request's first
-    # decode step in the same tick: its result and its one row
+    # decode step in the same tick: its ids and flags in one read
     eng.step()
     assert eng.stats.prefill_chunks == 4 and eng.stats.decode_steps == 1
-    assert eng.stats.host_syncs == 3 + 1 + 1 + 2
+    assert eng.stats.host_syncs == 3 + 1 + 1 + 1
 
 
 def test_a_synchronous_prefill_syncs_once(model):
