@@ -15,6 +15,6 @@ def read(run):
     if spent <= 0:
         return None
     calls = [run.work.attn_call(step) for step in observe.decode_contexts(run)
-             if step] * run.work.layers
+             if step] * run.work.attn_layers
     least, bound = counts.least_time(calls, run.peaks)
     return {"value": 100.0 * least / spent, "bound": bound}

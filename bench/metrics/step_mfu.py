@@ -1,6 +1,7 @@
-"""Operations the window's tokens need (``counts.Work``: surviving weights,
-routed experts only, attention over the real context, the LM head where a
-logit is used) over the window's seconds times the device's bf16 peak, %."""
+"""Operations the window's tokens need (the block's work module,
+``bench/work/<name>.py``: surviving weights, routed experts only, attention
+over the real context, the LM head where a logit is used) over the window's
+seconds times the device's bf16 peak, %."""
 
 from bench import observe
 

@@ -1,7 +1,7 @@
 """Roofline share of the weight-stream kernels (``_bsmm_mc_kernel`` walk,
 ``_fused_kernel`` gate+up): the least time their needed operations and
-bytes take at the device's peaks (``counts.Work.stream_calls``) over the
-summed device time of those kernels in the trace, %."""
+bytes take at the device's peaks (``stream_calls`` of the block's work
+module) over the summed device time of those kernels in the trace, %."""
 
 from bench import counts, observe
 

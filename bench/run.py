@@ -24,6 +24,7 @@ from __future__ import annotations
 import argparse
 import collections
 import contextlib
+import dataclasses
 import gc
 import json
 import os
@@ -109,6 +110,9 @@ class Session:
         self._log_phase("build")
         log("weights and packing: " + ", ".join(
             f"{k} {v:.3f} s" for k, v in self.built.times.items()))
+        if self.built.unchecked:
+            log(f"model keys the program has no field for, left to the "
+                f"reference: {', '.join(self.built.unchecked)}")
         self.engine = self.built.new_engine(self.mix)
         self.P, self.cfg = self.built.P, self.built.cfg
         self.seated, self.arrivals = traffic.generate(
@@ -258,14 +262,15 @@ class Session:
                 + str(collections.Counter(self.compiles).most_common(8)))
 
     def run_record(self) -> Run:
-        from bench import counts, sut
+        from bench import sut
 
         return Run(cell=self.cell, conf=self.conf, mix=self.mix,
                    records=self.records, t0=self.t0, t1=self.t1,
                    seconds=self.t1 - self.t0, steps=self.steps,
                    stats0=self.stats0, stats1=self.stats1, setup_s=self.setup_s,
                    trace=self.trace, peaks=self.peaks,
-                   work=counts.Work.of(sut.sizes(self.conf, self.rehearse)),
+                   work=spec.work(self.conf["reference"]).of(
+                       sut.sizes(self.conf, self.rehearse)),
                    memory_peak=self.memory_peak, max_batch=self.engine.max_batch,
                    prefill_chunk=self.engine.prefill_chunk)
 
@@ -346,10 +351,11 @@ def _open_loop(engine, P, arrivals, records, seconds, annotate):
 
 
 def _stats(engine) -> dict:
+    """Every integer counter of ``engine.stats``, so that a reader can take
+    a counter the program adds later."""
     s = engine.stats
-    return {k: getattr(s, k) for k in (
-        "decode_steps", "decode_tokens", "prefill_tokens", "prefill_chunks",
-        "completed", "failed", "timed_out")}
+    return {f.name: getattr(s, f.name) for f in dataclasses.fields(s)
+            if isinstance(getattr(s, f.name), int)}
 
 
 def run_once(args) -> dict:

@@ -3,8 +3,9 @@
 The engine marks its tick ``engine.step`` and each phase of it with a span
 of its own (``engine.decode``, ``engine.fetch``, ``engine.sample``, ...;
 ``docs/architecture.md``, "Spans and counters").  ``bench.tracing.reduce``
-keeps the harness's ``bench.*`` spans only, so this module reads the
-``engine.*`` ones from the same ``.xplane.pb``, as kept by
+keeps them, with their arguments, as ``Trace.program``; the tick readers
+(``bench/metrics/tick_*.py``, ``prefill_chunk_ms.py``) and the command line
+take the tick from there.  From a trace kept by
 ``python3 -m bench.run ... --trace 1 --keep-trace DIR``:
 
     python3 -m bench.spans DIR
@@ -21,7 +22,6 @@ from __future__ import annotations
 import argparse
 import bisect
 import collections
-import dataclasses
 import json
 import os
 import sys
@@ -29,44 +29,14 @@ import sys
 from bench import tracing
 from bench.tracing import Event
 
-PREFIX = "engine."
-
-
-@dataclasses.dataclass
-class Span:
-    """A host span of the program, its arguments, and the host line (thread)
-    it ran on."""
-    start: int  # ns
-    end: int
-    name: str
-    args: dict = dataclasses.field(default_factory=dict)
-    line: str = ""
-
-
-def program_spans(pd) -> list:
-    """The ``engine.*`` host events of a ``jax.profiler.ProfileData``."""
-    out = []
-    for plane in pd.planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for i, line in enumerate(plane.lines):
-            for ev in line.events:
-                if ev.name.startswith(PREFIX):
-                    s = int(ev.start_ns)
-                    out.append(Span(s, s + int(ev.duration_ns), ev.name,
-                                    dict(ev.stats), f"{plane.name}/{i}"))
-    return out
-
 
 def read(path: str) -> tuple:
     """(``tracing.Trace``, program spans) of a ``.xplane.pb``, or of the one
     file under a directory."""
-    from jax.profiler import ProfileData
-
     if os.path.isdir(path):
         path = tracing.trace_file(path)
-    pd = ProfileData.from_file(path)
-    return tracing.reduce(pd), program_spans(pd)
+    trace = tracing.reduce_file(path)
+    return trace, trace.program
 
 
 def _in_window(trace, program) -> list:

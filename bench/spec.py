@@ -5,10 +5,12 @@ Layout under ``bench/``:
 * ``configs/<file>``: a configuration (named by its ``file`` entry);
 * ``traffic/<traffic>.json``: a traffic mix, read by ``bench/traffic.py``;
 * ``metrics/<metric>.py``: one reader per metric, ``read(run) -> value``;
-* ``reference/<name>.py``: a plain reference, named by a configuration.
+* ``reference/<name>.py``: a plain reference, named by a configuration;
+* ``work/<name>.py``: the operations and bytes of the same block
+  (``of(sizes)``), found by the reference's name.
 
-A later cell, configuration, traffic mix or metric is a new file here and a
-new entry in ``BENCHMARK.json``; nothing that exists needs an edit.
+A later cell, configuration, traffic mix, metric or block is a new file here
+and a new entry in ``BENCHMARK.json``; nothing that exists needs an edit.
 """
 
 from __future__ import annotations
@@ -16,6 +18,7 @@ from __future__ import annotations
 import importlib.util
 import json
 import re
+import sys
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
@@ -67,6 +70,7 @@ def _module(path: Path, label: str):
     mod_name = "bench_" + re.sub(r"\W", "_", path.stem) + "_" + path.parent.name
     spec = importlib.util.spec_from_file_location(mod_name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[mod_name] = mod  # a dataclass looks its module up by name
     spec.loader.exec_module(mod)
     return mod
 
@@ -81,6 +85,12 @@ def reference(name: str):
     """The module ``reference/<name>.py`` (a plain reference)."""
     return _module(HERE / "reference" / f"{_checked(name)}.py",
                    f"reference {name}")
+
+
+def work(name: str):
+    """The module ``work/<name>.py``: the counts of reference ``name``'s
+    block."""
+    return _module(HERE / "work" / f"{_checked(name)}.py", f"work {name}")
 
 
 def metrics_for(bench: dict, cell_name: str, section: str) -> list:

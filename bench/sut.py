@@ -52,8 +52,14 @@ def sizes(conf: dict, smoke: bool) -> dict:
     return out
 
 
-def agree(cfg, model: dict) -> None:
-    """The program's model must be the one the configuration file states."""
+def agree(cfg, model: dict) -> list:
+    """The program's model must be the one the configuration file states.
+    Each key of the ``model`` section is held to the program's config: the
+    keys below by the field they name, any other by the ``ModelConfig``
+    field of the same name, else by the ``MoEConfig`` one.  A key the
+    section leaves out is not checked.  Returns the stated keys the program
+    has no field for; the reference still reads them, so ``correct``
+    catches a mismatch there."""
     moe = cfg.moe
     got = {"layers": cfg.n_layers, "d_model": cfg.d_model, "heads": cfg.n_heads,
            "kv_heads": cfg.n_kv_heads, "head_dim": cfg.hd, "d_ff": cfg.d_ff,
@@ -62,9 +68,24 @@ def agree(cfg, model: dict) -> None:
            "experts": moe.n_experts if moe else 0,
            "top_k": moe.top_k if moe else 0,
            "expert_d_ff": moe.expert_d_ff if moe else 0}
-    bad = {k: (v, model[k]) for k, v in got.items() if model[k] != v}
+    fields = [(c, {f.name for f in dataclasses.fields(c)})
+              for c in (cfg, moe) if c is not None]
+    bad, unchecked = {}, []
+    for key, want in model.items():
+        if key in got:
+            value = got[key]
+        else:
+            owner = next((c for c, names in fields if key in names), None)
+            if owner is None:
+                unchecked.append(key)
+                continue
+            value = getattr(owner, key)
+            value = list(value) if isinstance(value, tuple) else value
+        if value != want:
+            bad[key] = (value, want)
     if bad:
         raise ValueError(f"program model differs from the configuration: {bad}")
+    return unchecked
 
 
 def model_config(P, conf: dict, smoke: bool):
@@ -90,6 +111,7 @@ class Built:
     serving: dict
     seed: int
     times: dict
+    unchecked: list  # model keys the program has no field for (``agree``)
 
     def new_engine(self, mix: dict):
         """A ``ServingEngine`` over the packed plan, with the traffic's
@@ -122,7 +144,7 @@ def build(conf: dict, seed: int, smoke: bool) -> Built:
     P = program()
     sz = sizes(conf, smoke)
     cfg = model_config(P, conf, smoke)
-    agree(cfg, sz["model"])
+    unchecked = agree(cfg, sz["model"])
     api = P.get_api(cfg)
     times = {}
     t = time.monotonic()
@@ -135,7 +157,8 @@ def build(conf: dict, seed: int, smoke: bool) -> Built:
     del dense
     jax.block_until_ready(plan.params)
     times["pack_s"] = time.monotonic() - t
-    return Built(P, cfg, api, shapes, plan, sz["serving"], seed, times)
+    return Built(P, cfg, api, shapes, plan, sz["serving"], seed, times,
+                 unchecked)
 
 
 def lowered_steps(engine) -> str:

@@ -6,6 +6,7 @@ import pytest
 from bench import counts, spec, sut, weights
 
 BENCH = spec.benchmark()
+DECODER = spec.work("decoder")
 
 
 @pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
@@ -20,19 +21,17 @@ def test_bytes_match_the_plan(name):
     plan = api.compress(cfg, dense, sut.plan_config(P, sizes["plan"]))
     theirs = sum(lp.bytes for p, lp in plan.leaves.items()
                  if not p.endswith("scale"))
-    work = counts.Work.of(sizes)
+    work = spec.work(conf["reference"]).of(sizes)
     assert work.plan_bytes() == pytest.approx(theirs, rel=0, abs=0.5)
     sparse = [p for p, lp in plan.leaves.items() if lp.kind == "quant_sparse"]
     assert sparse, "the smoke plan packs no sparse weight"
     surviving = sum(plan.leaves[p].surviving for p in sparse)
-    mine = sum(m.weights * m.count * work.layers for m in work.matrices
-               if m.kind == "sparse")
-    assert mine == surviving
+    assert work.sparse_weights() == surviving
 
 
 def test_needed_work_counts_routed_experts_only():
     conf = spec.configuration(BENCH, "granite-moe-1b-a400m")
-    w = counts.Work.of(sut.sizes(conf, smoke=False))
+    w = DECODER.of(sut.sizes(conf, smoke=False))
     per_expert = sum(m.weights for m in w.matrices if m.count > 1)
     dense = sum(m.weights for m in w.matrices if m.count == 1)
     assert w.layer_weights_per_token() == w.layers * (8 * per_expert + dense)
@@ -50,7 +49,7 @@ def test_least_time_takes_each_call_bound():
 
 def test_attention_counts_live_contexts():
     conf = spec.configuration(BENCH, "glm4-9b-8l")
-    w = counts.Work.of(sut.sizes(conf, smoke=False))
+    w = DECODER.of(sut.sizes(conf, smoke=False))
     f, b = w.attn_call([100, 300])
     assert f == 4.0 * 400 * 32 * 128
     assert b == pytest.approx(400 * 2 * (2 * 128 + 8) + 4.0 * 2 * 32 * 128)

@@ -12,8 +12,7 @@ import os
 import pytest
 
 from bench import spans, tracing
-from bench.spans import Span
-from bench.tracing import Event, Trace
+from bench.tracing import Event, Span, Trace
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 WITH_SPANS = os.path.join(DATA, "cpu-rehearsal-spans.xplane.pb")

@@ -42,6 +42,12 @@ def test_cell_parts_found_by_name(cell):
 
 
 @pytest.mark.parametrize("conf", BENCH["configs"], ids=lambda c: c["name"])
+def test_work_module_found_by_the_reference_name(conf):
+    body = spec.configuration(BENCH, conf["name"])
+    assert callable(spec.work(body["reference"]).of)
+
+
+@pytest.mark.parametrize("conf", BENCH["configs"], ids=lambda c: c["name"])
 def test_configuration_file(conf):
     assert set(conf) == {"name", "source", "file", "reduced", "why"}
     body = spec.configuration(BENCH, conf["name"])
@@ -78,3 +84,5 @@ def test_unknown_names_are_refused():
         spec.traffic("../configs/x")
     with pytest.raises(spec.SpecError):
         spec.reader("no_such_metric")
+    with pytest.raises(spec.SpecError):
+        spec.work("no_such_block")

@@ -27,6 +27,7 @@ import os
 import re
 
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "engine."
 _MLIR_TYPES = {"i32": "s32", "i8": "s8", "i1": "pred", "bf16": "bf16",
                "f32": "f32", "f16": "f16", "i16": "s16", "i64": "s64",
                "ui8": "u8", "ui32": "u32"}
@@ -43,6 +44,17 @@ class Event:
 
 
 @dataclasses.dataclass
+class Span:
+    """A host span of the program, its arguments, and the host line (thread)
+    it ran on."""
+    start: int  # ns
+    end: int
+    name: str
+    args: dict = dataclasses.field(default_factory=dict)
+    line: str = ""
+
+
+@dataclasses.dataclass
 class Trace:
     t0: int
     t1: int
@@ -51,6 +63,7 @@ class Trace:
     modules: list  # Event
     spans: list  # Event (harness host spans)
     kernels: dict = dataclasses.field(default_factory=dict)  # signature -> name
+    program: list = dataclasses.field(default_factory=list)  # Span, unclipped
 
     @property
     def window_s(self) -> float:
@@ -184,7 +197,7 @@ def _program(ev) -> str:
 
 def reduce(pd) -> Trace:
     """Reduce a ``jax.profiler.ProfileData``."""
-    spans, ops, modules = [], [], []
+    spans, program, ops, modules = [], [], [], []
     chips = 0
     for plane in pd.planes:
         if plane.name.startswith("/device:") and "CPU" not in plane.name:
@@ -201,11 +214,14 @@ def reduce(pd) -> Trace:
                     modules.append(Event(s, s + int(ev.duration_ns),
                                          _program(ev)))
         elif plane.name.startswith("/host:"):
-            for line in plane.lines:
+            for i, line in enumerate(plane.lines):
                 for ev in line.events:
+                    s = int(ev.start_ns)
                     if ev.name.startswith(SPAN_PREFIX):
-                        s = int(ev.start_ns)
                         spans.append(Event(s, s + int(ev.duration_ns), ev.name))
+                    elif ev.name.startswith(PROGRAM_PREFIX):
+                        program.append(Span(s, s + int(ev.duration_ns), ev.name,
+                                            dict(ev.stats), f"{plane.name}/{i}"))
     if not spans:
         raise ValueError("trace holds no harness span")
     t0 = min(s.start for s in spans)
@@ -215,7 +231,8 @@ def reduce(pd) -> Trace:
         return [Event(max(e.start, t0), min(e.end, t1), e.name, e.text)
                 for e in evs if e.end > t0 and e.start < t1]
 
-    return Trace(t0, t1, chips, clip(ops), clip(modules), spans)
+    return Trace(t0, t1, chips, clip(ops), clip(modules), spans,
+                 program=program)
 
 
 def trace_file(directory: str) -> str:
