@@ -26,6 +26,7 @@ _MODULES = {
     "gemma3-4b": "gemma3_4b",
     "xlstm-350m": "xlstm_350m",
     "recurrentgemma-2b": "recurrentgemma_2b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 ARCH_IDS = tuple(_MODULES)

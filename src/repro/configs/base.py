@@ -42,6 +42,11 @@ class ModelConfig:
     head_dim: int = 0  # 0 -> d_model // n_heads
     activation: str = "silu"
     norm: str = "rmsnorm"  # rmsnorm | layernorm
+    # epsilon of every norm of the stack (the source's ``rms_norm_eps``)
+    rms_norm_eps: float = 1e-6
+    # "rope" (rotary on q and k) | "nope" (no positional embedding at all:
+    # granite-4.0-h's attention layers, whose Mamba layers carry position)
+    position_embedding: str = "rope"
     rope_base: float = 10000.0
     rope_base_global: float = 0.0  # gemma3: separate base for global layers
     tie_embeddings: bool = True
@@ -67,6 +72,20 @@ class ModelConfig:
     # ssm / hybrid cell sizes
     conv_width: int = 4
     lru_dim: int = 0  # RG-LRU width (recurrentgemma: ~d_model)
+    # Mamba-2 mixer (``mamba`` layer kind, models/mamba2.py), under the
+    # source's names: heads of ``mamba_d_head`` channels (d_inner =
+    # n_heads * d_head = expand * d_model), a state of ``mamba_d_state``
+    # per channel, B and C shared by the heads of each of ``mamba_n_groups``
+    # groups, a causal depthwise conv of width ``mamba_d_conv`` over
+    # (x, B, C), and the SSD scan over chunks of ``mamba_chunk_size``.
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1
+    mamba_expand: int = 2
+    mamba_d_conv: int = 4
+    mamba_conv_bias: bool = True
+    mamba_chunk_size: int = 256
 
     # numerics
     param_dtype: str = "float32"
@@ -78,6 +97,15 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels of the conv: x, then B and C of every group."""
+        return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
 
     @property
     def layer_kinds(self) -> tuple:
@@ -111,6 +139,12 @@ class ModelConfig:
             elif k == "rec":
                 w = self.lru_dim or d
                 total += 2 * d * w + w * d + 3 * w + w * self.conv_width + 2 * d
+                total += per_dense_ffn
+            elif k == "mamba":
+                di, cd, nh = self.mamba_d_inner, self.mamba_conv_dim, self.mamba_n_heads
+                total += d * (di + cd + nh) + di * d  # in_proj, out_proj
+                total += cd * (self.mamba_d_conv + int(self.mamba_conv_bias))
+                total += 3 * nh + di + 2 * d  # dt_bias, A_log, D, norms
                 total += per_dense_ffn
             elif k == "mlstm":
                 up = 2 * d

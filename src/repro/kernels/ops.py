@@ -18,6 +18,7 @@ from repro.kernels import block_sparse as _bs
 from repro.kernels import flash_attention as _fa
 from repro.kernels import fused_gate_up as _fgu
 from repro.kernels import quant_matmul as _qmm
+from repro.kernels import ssm_decode as _ssm
 
 
 def _on_tpu() -> bool:
@@ -320,3 +321,14 @@ def q78_matmul(
         ap, wp, block_b=block_b, block_n=block_n, block_k=block_k, interpret=interpret
     )
     return y[:B, :N]
+
+
+def ssm_decode(state, layer, x, dt, a, d, b, c, interpret: bool | None = None):
+    """One Mamba-2 decode step of layer ``layer`` over every row, the
+    stacked state ``(L, B, N, H*P)`` updated in place
+    (``kernels/ssm_decode``; argument layout there).  Not jitted here: it
+    runs inside the decode step's layer scan, whose carried state buffer
+    the kernel aliases."""
+    if interpret is None:
+        interpret = not _on_tpu()
+    return _ssm.ssm_decode(state, layer, x, dt, a, d, b, c, interpret=interpret)

@@ -135,6 +135,35 @@ def supports_spec_decode(cfg) -> bool:
     return bool(kinds) and all(k in ("global", "local") for k in kinds)
 
 
+def supports_chunked_prefill(cfg) -> bool:
+    """Whether the engine may prefill this stack in chunks: a chunk is a
+    multi-token decode step from the state the previous chunk left, and the
+    last chunk is padded to the chunk width (serving/scheduler.py).
+
+    Stacks that qualify for speculation do (their positionally-addressed
+    caches mask what lies past the frontier).  So does a decoder-only stack
+    of paged-width attention (``global``) and Mamba-2 layers: the Mamba
+    state is carried from chunk to chunk exactly, and a padded position
+    changes neither it nor the conv state (``models/mamba2.py``).  Other
+    recurrent kinds (RG-LRU, xLSTM) would take padded positions into their
+    state, and a sliding-window ring would let them overwrite live
+    entries."""
+    if supports_spec_decode(cfg):
+        return True
+    if get_api(cfg) is not _TRANSFORMER_API:
+        return False
+    kinds = getattr(cfg, "layer_kinds", ()) or ()
+    return bool(kinds) and all(k in ("global", "mamba") for k in kinds)
+
+
+def carries_state(cfg) -> bool:
+    """Whether the stack holds per-sequence state that a step rewrites in
+    place (Mamba-2 layers): chunked prefill then runs non-overlapping spans
+    with a padded tail, and a finished prefill's state is inserted into its
+    slot in place."""
+    return "mamba" in (getattr(cfg, "layer_kinds", ()) or ())
+
+
 def supports_paged_kv(cfg) -> bool:
     """Whether this family serves through the paged KV cache.  Decoder-only
     transformer stacks thread the page table through their decode step; the

@@ -534,10 +534,14 @@ def apply_attn(
         k = sl.shard(k, "batch", "seq", "kv_heads", None)
         v = sl.shard(v, "batch", "seq", "kv_heads", None)
         base = rope_base if rope_base is not None else cfg.rope_base
+        if cfg.position_embedding == "nope":
+            rope = lambda t, positions, base: t  # no positional embedding
+        else:
+            rope = apply_rope
         if cache is None:
             positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
-            q = apply_rope(q, positions, base)
-            k = apply_rope(k, positions, base)
+            q = rope(q, positions, base)
+            k = rope(k, positions, base)
             o = attention(q, k, v, causal=True, window=window, softcap=cfg.logit_softcap)
             new_cache = None
         elif "k_pages" in cache:
@@ -547,8 +551,8 @@ def apply_attn(
             # S > 1 is the speculative verify step: all S draft positions
             # scatter and attend in this one step.
             positions = pos[:, None] + jnp.arange(S)[None]
-            q = apply_rope(q, positions, base)
-            k = apply_rope(k, positions, base)
+            q = rope(q, positions, base)
+            k = rope(k, positions, base)
             new_cache = dict(cache)
             if "k_scale_pages" in cache:
                 k, ks = quantize_kv(k)
@@ -576,8 +580,8 @@ def apply_attn(
             )
         else:
             positions = pos[:, None] + jnp.arange(S)[None]  # (B, S) decode span
-            q = apply_rope(q, positions, base)
-            k = apply_rope(k, positions, base)
+            q = rope(q, positions, base)
+            k = rope(k, positions, base)
             if "k_scale" in cache:
                 # int8 cache: quantize this step's K/V per (token, head) and
                 # write payload + scale; the read side folds the scales into

@@ -3,7 +3,8 @@ discipline.
 
 A model is a sequence of layer *kinds* (``cfg.layer_kinds``): ``global`` /
 ``local`` attention layers (with dense-MLP or MoE FFN), ``rec`` (RG-LRU)
-blocks, ``mlstm`` / ``slstm`` xLSTM blocks.  The kind sequence is factored
+blocks, ``mamba`` (Mamba-2 mixer + dense MLP) blocks, ``mlstm`` / ``slstm``
+xLSTM blocks.  The kind sequence is factored
 into its smallest repeating *unit*; parameters for each unit position are
 stacked across units and the forward pass is a single ``jax.lax.scan`` over
 units (plus an unrolled remainder).  HLO size is therefore O(unit), not
@@ -28,6 +29,7 @@ import jax.numpy as jnp
 from repro.core.weight_plan import apply_linear
 from repro.distributed import shardlib as sl
 from repro.models import layers as L
+from repro.models import mamba2 as MB
 from repro.models import moe as M
 from repro.models import recurrent as R
 from repro.models import ssm as S
@@ -105,6 +107,13 @@ def init_layer(cfg, kind: str, key):
             "ln2": L.init_norm(cfg.d_model, cfg.norm),
             "mlp": L.init_mlp(cfg, ks[1]),
         }
+    if kind == "mamba":
+        return {
+            "ln1": L.init_norm(cfg.d_model, cfg.norm),
+            "mamba": MB.init_mamba2(cfg, ks[0]),
+            "ln2": L.init_norm(cfg.d_model, cfg.norm),
+            "mlp": L.init_mlp(cfg, ks[1]),
+        }
     if kind == "mlstm":
         return {"ln": L.init_norm(cfg.d_model, cfg.norm), "cell": S.init_mlstm(cfg, ks[0])}
     if kind == "slstm":
@@ -123,6 +132,9 @@ def layer_axes(cfg, kind: str):
         return a
     if kind == "rec":
         return {"ln1": na, "rec": R.rglru_axes(), "ln2": na, "mlp": L.mlp_axes(cfg)}
+    if kind == "mamba":
+        return {"ln1": na, "mamba": MB.mamba2_axes(cfg), "ln2": na,
+                "mlp": L.mlp_axes(cfg)}
     if kind == "mlstm":
         return {"ln": na, "cell": S.mlstm_axes(cfg)}
     if kind == "slstm":
@@ -159,6 +171,8 @@ def init_layer_cache(cfg, kind: str, batch: int, length: int, dtype=jnp.bfloat16
         return L.init_attn_cache(cfg, batch, ln, kv_dtype if kv_dtype is not None else dtype)
     if kind == "rec":
         return R.init_rglru_state(cfg, batch, dtype)
+    if kind == "mamba":
+        return MB.init_conv_state(cfg, batch, dtype)
     if kind == "mlstm":
         return S.init_mlstm_state(cfg, batch, dtype)
     if kind == "slstm":
@@ -173,6 +187,8 @@ def layer_cache_axes(kind: str, quantized_kv: bool = False, paged: bool = False)
         return L.attn_cache_axes(quantized_kv)
     if kind == "rec":
         return R.rglru_state_axes()
+    if kind == "mamba":
+        return MB.conv_state_axes()
     if kind == "mlstm":
         return S.mlstm_state_axes()
     if kind == "slstm":
@@ -181,15 +197,18 @@ def layer_cache_axes(kind: str, quantized_kv: bool = False, paged: bool = False)
 
 
 def apply_layer(cfg, kind: str, p, x, *, mode: str, cache=None, pos=None,
-                page_table=None):
-    """Returns (x, new_cache, aux)."""
+                page_table=None, layer=None, valid=None):
+    """Returns (x, new_cache, aux).  A ``mamba`` layer's cache also holds
+    the stack's SSM state (``"ssm"``), of which it updates entry ``layer``;
+    ``valid`` (B, T) marks the real positions of a padded span."""
     aux = jnp.zeros((), jnp.float32)
+    eps = cfg.rms_norm_eps
     if kind in ATTN_KINDS:
         base = (
             cfg.rope_base_global
             if (kind == "global" and cfg.rope_base_global) else cfg.rope_base
         )
-        h = L.apply_norm(p["ln1"], x, cfg.norm)
+        h = L.apply_norm(p["ln1"], x, cfg.norm, eps)
         # sequence-parallel boundary: blocks consume seq-replicated
         # activations (one all-gather here when seq_sp -> model) and emit
         # seq-sharded ones (reduce-scatter at the block-output constraint).
@@ -207,7 +226,7 @@ def apply_layer(cfg, kind: str, p, x, *, mode: str, cache=None, pos=None,
         else:
             a, cache_a = L.apply_attn(cfg, p["attn"], h, kind=kind, rope_base=base)
         x = x + a
-        h = L.apply_norm(p["ln2"], x, cfg.norm)
+        h = L.apply_norm(p["ln2"], x, cfg.norm, eps)
         h = sl.shard_pinned(h, "batch", "seq", None)
         if cfg.moe is not None:
             if mode == "train":
@@ -218,17 +237,24 @@ def apply_layer(cfg, kind: str, p, x, *, mode: str, cache=None, pos=None,
             f = L.apply_mlp(cfg, p["mlp"], h)
         return x + f, cache_a, aux
     if kind == "rec":
-        h = L.apply_norm(p["ln1"], x, cfg.norm)
+        h = L.apply_norm(p["ln1"], x, cfg.norm, eps)
         y, new_state = R.apply_rglru(cfg, p["rec"], h, cache)
         x = x + y
-        h = L.apply_norm(p["ln2"], x, cfg.norm)
+        h = L.apply_norm(p["ln2"], x, cfg.norm, eps)
+        return x + L.apply_mlp(cfg, p["mlp"], h), new_state, aux
+    if kind == "mamba":
+        h = L.apply_norm(p["ln1"], x, cfg.norm, eps)
+        y, new_state = MB.apply_mamba2(cfg, p["mamba"], h, cache, layer=layer,
+                                       valid=valid)
+        x = x + y
+        h = L.apply_norm(p["ln2"], x, cfg.norm, eps)
         return x + L.apply_mlp(cfg, p["mlp"], h), new_state, aux
     if kind == "mlstm":
-        h = L.apply_norm(p["ln"], x, cfg.norm)
+        h = L.apply_norm(p["ln"], x, cfg.norm, eps)
         y, new_state = S.apply_mlstm(cfg, p["cell"], h, cache)
         return x + y, new_state, aux
     if kind == "slstm":
-        h = L.apply_norm(p["ln"], x, cfg.norm)
+        h = L.apply_norm(p["ln"], x, cfg.norm, eps)
         y, new_state = S.apply_slstm(cfg, p["cell"], h, cache)
         return x + y, new_state, aux
     raise ValueError(kind)
@@ -247,9 +273,10 @@ def _attn_prefill(cfg, p, h, kind, base, cache):
     q = apply_linear(h, p["wq"]).reshape(B, Sq, H, hd)
     k = apply_linear(h, p["wk"]).reshape(B, Sq, KVH, hd)
     v = apply_linear(h, p["wv"]).reshape(B, Sq, KVH, hd)
-    positions = jnp.broadcast_to(jnp.arange(Sq)[None], (B, Sq))
-    q = L.apply_rope(q, positions, base)
-    k = L.apply_rope(k, positions, base)
+    if cfg.position_embedding != "nope":
+        positions = jnp.broadcast_to(jnp.arange(Sq)[None], (B, Sq))
+        q = L.apply_rope(q, positions, base)
+        k = L.apply_rope(k, positions, base)
     o = L.attention(q, k, v, causal=True, window=window, softcap=cfg.logit_softcap)
     out = apply_linear(o.reshape(B, Sq, H * hd), p["wo"])
     Sc = cache["k"].shape[1]
@@ -342,6 +369,10 @@ def init_cache(cfg, batch: int, length: int, dtype=jnp.bfloat16, kv_dtype=None,
     if page_size is not None:
         pages_per_seq = -(-length // page_size)
         cache["page_table"] = jnp.zeros((batch, pages_per_seq), jnp.int32)
+    n_mamba = cfg.layer_kinds.count("mamba")
+    if n_mamba:
+        # one leaf for every Mamba layer's SSM state (models/mamba2.py)
+        cache["ssm"] = MB.init_ssm_state(cfg, n_mamba, batch)
     return cache
 
 
@@ -366,27 +397,49 @@ def cache_axes(cfg, quantized_kv: bool = False, paged: bool = False):
     }
     if paged:
         axes["page_table"] = _PAGE_TABLE_AXES
+    if "mamba" in cfg.layer_kinds:
+        axes["ssm"] = MB.SSM_AXES
     return axes
 
 
-def _run_layers(cfg, params, x, *, mode: str, cache=None, pos=None):
+def _run_layers(cfg, params, x, *, mode: str, cache=None, pos=None,
+                valid=None):
     """Scan the unit stack, then the remainder.  Returns (x, new_cache, aux).
 
     A paged cache carries its ``page_table`` alongside the layer caches; it
     is read-only inside the step (the engine owns allocation), so it rides
     into the scan bodies as a closure constant and is reattached to the
-    returned cache unchanged."""
+    returned cache unchanged.
+
+    A stack with Mamba layers carries their stacked SSM state (``"ssm"``)
+    through the scans' carry, not their per-layer inputs: every Mamba layer
+    updates its own entry of the one buffer in place (index: unit iteration
+    times the unit's Mamba layers, plus the layer's ordinal in the unit)."""
     unit, n_units, rem = find_unit(cfg.layer_kinds)
     page_table = cache.get("page_table") if cache is not None else None
+    ssm = cache.get("ssm") if cache is not None else None
 
     remat = mode == "train" and getattr(cfg, "remat", False)
 
     def one_layer(kind, p, x):
         return apply_layer(cfg, kind, p, x, mode=mode, cache=None, pos=None)
 
+    def stateful(kind, p, x, c, ssm, layer):
+        """One Mamba layer with the stack's state: (x, conv cache, aux, ssm)."""
+        x, nc, a = apply_layer(cfg, kind, p, x, mode=mode,
+                               cache={**c, "ssm": ssm}, layer=layer,
+                               valid=valid)
+        nc = dict(nc)
+        return x, nc, a, nc.pop("ssm")
+
+    m_unit = [unit[:pi].count("mamba") for pi in range(len(unit))]
+    n_mu = unit.count("mamba")
+
     def unit_body(carry, xs):
-        x, aux = carry
-        layer_ps, layer_cs = xs
+        if ssm is None:
+            (x, aux), (layer_ps, layer_cs) = carry, xs
+        else:
+            (x, aux, st), (layer_ps, layer_cs, it) = carry, xs
         new_cs = []
         for pi, kind in enumerate(unit):
             c = layer_cs[pi] if layer_cs is not None else None
@@ -394,37 +447,63 @@ def _run_layers(cfg, params, x, *, mode: str, cache=None, pos=None):
                 x, nc, a = jax.checkpoint(
                     functools.partial(one_layer, kind), static_argnums=()
                 )(layer_ps[pi], x)
+            elif kind == "mamba" and ssm is not None:
+                x, nc, a, st = stateful(kind, layer_ps[pi], x, c, st,
+                                        it * n_mu + m_unit[pi])
             else:
                 x, nc, a = apply_layer(cfg, kind, layer_ps[pi], x, mode=mode, cache=c,
-                                       pos=pos, page_table=page_table)
+                                       pos=pos, page_table=page_table, valid=valid)
             new_cs.append(nc)
             aux = aux + a
-        return (x, aux), tuple(new_cs) if cache is not None else None
+        new_carry = (x, aux) if ssm is None else (x, aux, st)
+        return new_carry, tuple(new_cs) if cache is not None else None
 
     xs = (params["unit"], tuple(cache["unit"]) if cache is not None else None)
-    (x, aux), unit_caches = jax.lax.scan(
-        unit_body, (x, jnp.zeros((), jnp.float32)), xs
-    )
+    carry = (x, jnp.zeros((), jnp.float32))
+    if ssm is not None:
+        xs = xs + (jnp.arange(n_units),)
+        carry = carry + (ssm,)
+    carry, unit_caches = jax.lax.scan(unit_body, carry, xs)
+    x, aux = carry[:2]
+    if ssm is not None:
+        ssm = carry[2]
+    m_base = n_units * n_mu
     rem_caches = []
     for ri, (kind, count) in enumerate(rem_runs(rem)):
-        def run_body(carry, xs_r, kind=kind):
-            x, aux = carry
-            p_r, c_r = xs_r
-            if remat:
-                x, nc, a = jax.checkpoint(functools.partial(one_layer, kind))(p_r, x)
-            else:
-                x, nc, a = apply_layer(cfg, kind, p_r, x, mode=mode, cache=c_r,
-                                       pos=pos, page_table=page_table)
-            return (x, aux + a), nc
+        def run_body(carry, xs_r, kind=kind, m_base=m_base):
+            if ssm is None or kind != "mamba":
+                x, aux = carry[:2]
+                p_r, c_r = xs_r[:2]
+                if remat:
+                    x, nc, a = jax.checkpoint(functools.partial(one_layer, kind))(p_r, x)
+                else:
+                    x, nc, a = apply_layer(cfg, kind, p_r, x, mode=mode, cache=c_r,
+                                           pos=pos, page_table=page_table,
+                                           valid=valid)
+                return (x, aux + a) + carry[2:], nc
+            (x, aux, st), (p_r, c_r, j) = carry, xs_r
+            x, nc, a, st = stateful(kind, p_r, x, c_r, st, m_base + j)
+            return (x, aux + a, st), nc
 
         xs_r = (params["rem"][ri], cache["rem"][ri] if cache is not None else None)
-        (x, aux), nc = jax.lax.scan(run_body, (x, aux), xs_r)
+        carry = (x, aux)
+        if ssm is not None:
+            xs_r = xs_r + (jnp.arange(count),)
+            carry = carry + (ssm,)
+        carry, nc = jax.lax.scan(run_body, carry, xs_r)
+        x, aux = carry[:2]
+        if ssm is not None:
+            ssm = carry[2]
+        if kind == "mamba":
+            m_base += count
         rem_caches.append(nc)
     if cache is None:
         return x, None, aux
     new_cache = {"unit": list(unit_caches), "rem": rem_caches}
     if page_table is not None:
         new_cache["page_table"] = page_table
+    if ssm is not None:
+        new_cache["ssm"] = ssm
     return x, new_cache, aux
 
 
@@ -439,7 +518,7 @@ def forward(cfg, params, tokens, extra_embeds: Optional[jax.Array] = None):
         x = jnp.concatenate([extra_embeds.astype(x.dtype), x], axis=1)
         x = sl.shard(x, "batch", "seq_sp", None)
     x, _, aux = _run_layers(cfg, params, x, mode="train")
-    x = L.apply_norm(params["final_norm"], x, cfg.norm)
+    x = L.apply_norm(params["final_norm"], x, cfg.norm, cfg.rms_norm_eps)
     logits = L.unembed(cfg, params["embed"], x)
     if extra_embeds is not None:
         logits = logits[:, extra_embeds.shape[1]:]
@@ -452,7 +531,7 @@ def prefill(cfg, params, tokens, cache, extra_embeds: Optional[jax.Array] = None
     if extra_embeds is not None:
         x = jnp.concatenate([extra_embeds.astype(x.dtype), x], axis=1)
     x, cache, _ = _run_layers(cfg, params, x, mode="prefill", cache=cache)
-    x = L.apply_norm(params["final_norm"], x[:, -1:], cfg.norm)
+    x = L.apply_norm(params["final_norm"], x[:, -1:], cfg.norm, cfg.rms_norm_eps)
     logits = L.unembed(cfg, params["embed"], x)
     return logits, cache
 
@@ -466,10 +545,21 @@ def decode_step(cfg, params, cache, tokens, pos):
     per-position causal masking, so one weight stream serves all T draft
     positions (the paper's batch-processing amortization along the token
     axis).  Returns logits (B, T, vocab) — logits[:, t] predicts the token
-    after tokens[:, t]."""
+    after tokens[:, t].
+
+    In a stack that carries state (Mamba layers), a negative token marks a
+    padded position at the end of a span (the engine pads a chunked
+    prefill's tail to the chunk width): it changes no state, and its logits
+    are not read.  Its KV lands past the span's last real position, where
+    the next decode step's write replaces it before any query can see it."""
+    valid = None
+    if "mamba" in cfg.layer_kinds:
+        valid = tokens >= 0
+        tokens = jnp.maximum(tokens, 0)
     x = L.embed_tokens(cfg, params["embed"], tokens)
-    x, cache, _ = _run_layers(cfg, params, x, mode="decode", cache=cache, pos=pos)
-    x = L.apply_norm(params["final_norm"], x, cfg.norm)
+    x, cache, _ = _run_layers(cfg, params, x, mode="decode", cache=cache, pos=pos,
+                              valid=valid)
+    x = L.apply_norm(params["final_norm"], x, cfg.norm, cfg.rms_norm_eps)
     logits = L.unembed(cfg, params["embed"], x)
     return logits, cache
 

@@ -44,17 +44,31 @@ from typing import Any, Callable, Optional
 def positional_state_gate(cfg, feature: str) -> Optional[str]:
     """THE gate for features that need multi-token decode on positionally-
     addressed caches (``api.supports_spec_decode``): speculative decode and
-    chunked prefill both write a span of positions ahead of the committed
-    frontier and rely on position masking to hide the uncommitted tail.
-    Returns None when ``cfg`` qualifies, else the one shared error text —
-    previously duplicated with drifting wording at the engine's two check
-    sites."""
+    prefix sharing write or map positions and rely on position masking or
+    position identity, which state a step rewrites in place cannot give (a
+    rejected draft's update cannot be undone; a shared prefix's state is
+    not in its pages).  Returns None when ``cfg`` qualifies, else the one
+    shared error text."""
     from repro.models.api import supports_spec_decode
 
     if supports_spec_decode(cfg):
         return None
     return (f"{cfg.name}: {feature} needs multi-token decode on a "
             f"positionally-addressed cache ({cfg.family} does not qualify)")
+
+
+def chunked_prefill_gate(cfg) -> Optional[str]:
+    """The gate for chunked prefill (``api.supports_chunked_prefill``):
+    positionally-addressed stacks, and stacks whose state is carried from
+    chunk to chunk exactly (Mamba-2 beside paged attention).  Returns None
+    when ``cfg`` qualifies, else the error text."""
+    from repro.models.api import supports_chunked_prefill
+
+    if supports_chunked_prefill(cfg):
+        return None
+    return (f"{cfg.name}: chunked prefill needs a positionally-addressed "
+            f"cache or state carried exactly from chunk to chunk "
+            f"({cfg.family} does not qualify)")
 
 
 @dataclasses.dataclass(frozen=True)

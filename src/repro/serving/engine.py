@@ -149,13 +149,18 @@ from repro.core.batching import BatchSizer
 from repro.distributed import shardlib as sl
 from repro.distributed.fault import HeartbeatMonitor
 from repro.models.api import (
+    carries_state,
     get_api,
     kv_bytes_per_token,
     supports_int8_kv,
     supports_paged_kv,
 )
 from repro.models.layers import finite_rows, write_pages
-from repro.serving.config import EngineConfig, positional_state_gate
+from repro.serving.config import (
+    EngineConfig,
+    chunked_prefill_gate,
+    positional_state_gate,
+)
 from repro.serving.faultinject import FaultInjected
 from repro.serving.paged import (
     NULL_PAGE,
@@ -310,6 +315,9 @@ class EngineStats:
     # plain-tick rows whose token came from the tick's one batched greedy
     # choice (equals decode_tokens when every request is greedy)
     batched_choices: int = 0
+    # finished prefills whose recurrent state (Mamba-2) was written into
+    # their slot in place, one per ``engine.state_insert`` span
+    state_inserts: int = 0
 
     @property
     def mean_batch(self) -> float:
@@ -422,8 +430,8 @@ class ServingEngine:
         # stream exactly like batch samples).  Needs positionally-addressed
         # caches on BOTH models so rejected writes are masked-then-
         # overwritten instead of rolled back — SpecConfig.validated_k is
-        # the single validated check (shared with the chunked-prefill gate
-        # below via config.positional_state_gate).
+        # the single validated check (config.positional_state_gate, which
+        # prefix sharing shares below).
         self.spec_k = pc.validated_k(cfg)
         self.draft_cfg = draft_cfg
         self.draft_params = draft_params
@@ -434,14 +442,18 @@ class ServingEngine:
         # ring entries invisible until overwritten, multi-position decode).
         # Attention over a causal prefix is a pure function of (tokens,
         # positions, params), so the chunked logits — and the first sampled
-        # token — are bit-identical to the one-shot prefill's.
+        # token — are bit-identical to the one-shot prefill's.  A stack that
+        # carries state (Mamba-2) qualifies too: its chunks run without
+        # overlap from the state the previous chunk left, the last padded
+        # to the chunk width (serving/scheduler.py).
+        self.carries_state = carries_state(cfg)
         self.prefill_chunk = self.prefill_budget = None
         if prefill_chunk is not None:
             prefill_chunk = int(prefill_chunk)
             if prefill_chunk <= 0:
                 raise ValueError(
                     f"prefill_chunk must be positive, got {prefill_chunk}")
-            reason = positional_state_gate(cfg, "chunked prefill")
+            reason = chunked_prefill_gate(cfg)
             if reason is not None:
                 import warnings
 
@@ -456,6 +468,11 @@ class ServingEngine:
                 self.prefill_chunk = prefill_chunk
                 self.prefill_budget = max(
                     int(prefill_budget or 0), prefill_chunk)
+                if self.carries_state:
+                    # whole chunks, so that a padded tail never wraps the
+                    # private prefill cache onto its first positions
+                    max_len = -(-max_len // prefill_chunk) * prefill_chunk
+                    self.max_len = max_len
         # the cache stream the sizer charges: per-token bytes at this
         # engine's cache dtype and the *expected* context — max_len for the
         # contiguous cache (the reservation is real traffic: ring length ==
@@ -577,6 +594,17 @@ class ServingEngine:
                     1 + max_batch * self.pages_per_seq)
                 self.allocator = PageAllocator(self.num_pages)
                 self._owns_allocator = True
+            if share_prefix and self.carries_state:
+                # a shared prefix maps pages: the recurrent state those
+                # tokens built is not in them, and the new sequence would
+                # start from a state that never saw its prefix.
+                import warnings
+
+                warnings.warn(
+                    positional_state_gate(cfg, "prefix sharing")
+                    + ": its recurrent state is not held in pages; serving "
+                    "without prefix sharing", stacklevel=2)
+                share_prefix = False
             if share_prefix and self.api.extra_keys:
                 # prefix sharing keys on prompt tokens only; this family's
                 # KV also depends on per-request frames/patches, so equal
@@ -693,6 +721,13 @@ class ServingEngine:
             logits = jnp.where(poison[:, None, None], jnp.nan, logits)
             return logits, finite_rows(logits), cache
 
+        # a finished prefill's state into its slot, in place: the pool is
+        # donated and each leaf takes the batch-1 row at its axis 1
+        self._insert_state_rows = jax.jit(
+            lambda pool, one, slot: jax.tree.map(
+                lambda p, o: jax.lax.dynamic_update_slice_in_dim(
+                    p, o.astype(p.dtype), slot, axis=1), pool, one),
+            donate_argnums=(0,))
         if self.mesh is None:
             self._decode = jax.jit(_decode_impl, donate_argnums=(1,))
             self._prefill1 = jax.jit(functools.partial(self._prefill_one_impl, cfg))
@@ -1077,7 +1112,7 @@ class ServingEngine:
             if not ok:
                 self._retry_or_fail(req, "non-finite prefill logits")
                 continue
-            self._write_slot(slot, cache1)
+            self._write_slot(slot, cache1, req.uid)
             self._start_slot(slot, req, S, tok, tokens, resumed)
 
     def _admit_paged(self):
@@ -1201,7 +1236,8 @@ class ServingEngine:
                 continue
             # shared positions [0, shared_len) already hold identical KV
             # (same tokens, same positions, same params): write only ours.
-            self._write_slot_paged(slot, cache1, start=shared_len, stop=S)
+            self._write_slot_paged(slot, cache1, start=shared_len, stop=S,
+                                   uid=req.uid)
             if self.registry is not None:
                 self.registry.register(prompt_key, pages[: math.ceil(S / ps)])
             self._start_slot(slot, req, S, tok, tokens, resumed)
@@ -1236,7 +1272,8 @@ class ServingEngine:
             while self.slot_prefill[slot] is not None:
                 job = self.slot_prefill[slot]
                 start, stop = next(
-                    (a, b) for a, b in chunk_spans(job.S, self.prefill_chunk)
+                    (a, b) for a, b in chunk_spans(
+                        job.S, self.prefill_chunk, self.carries_state)
                     if b > job.done)
                 if not budget.try_charge(stop - start):
                     self.last_tick_prefill_tokens = budget.used
@@ -1251,7 +1288,10 @@ class ServingEngine:
         batch-1 cache.  The final (possibly overlapped) chunk's last logits
         row is the full prefill's last row bit-for-bit (scheduler.py
         explains why the overlap is a no-op rewrite); a non-finite chunk
-        sends the request to the retry path like a poisoned inline prefill."""
+        sends the request to the retry path like a poisoned inline prefill.
+        In a stack that carries state a short span is padded to the chunk
+        width with token -1 (a position that changes no state), and the
+        last real row is read."""
         req = job.req
         with TraceAnnotation("engine.prefill_chunk", uid=req.uid, slot=slot,
                              start=start, stop=stop):
@@ -1260,7 +1300,11 @@ class ServingEngine:
                     self.cfg, 1, self.max_len, self.dtype,
                     kv_dtype=self.kv_dtype, **self._spec_cache_kw(),
                 )
-            toks = jnp.asarray(job.tokens[start:stop], jnp.int32)[None]
+            span = np.asarray(job.tokens[start:stop], np.int32)
+            if self.carries_state:
+                span = np.pad(span, (0, self.prefill_chunk - len(span)),
+                              constant_values=-1)
+            toks = jnp.asarray(span)[None]
             pos = jnp.asarray([start], jnp.int32)
             logits, ok, job.cache1 = self._decode(
                 self.params, job.cache1, toks, pos, jnp.zeros((1,), bool))
@@ -1283,7 +1327,7 @@ class ServingEngine:
                     slot, f"page pool exhausted during chunked prefill: {e}")
                 return
         if job.finished:
-            job.last_row = logits[:, -1]
+            job.last_row = logits[:, stop - start - 1]
             self._finish_prefill_job(slot, job)
 
     def _finish_prefill_job(self, slot: int, job: PrefillJob):
@@ -1307,7 +1351,8 @@ class ServingEngine:
                         slot, f"page pool exhausted at prefill completion: {e}")
                     return
                 self._write_slot_paged(slot, job.cache1,
-                                       start=job.shared_len, stop=job.S)
+                                       start=job.shared_len, stop=job.S,
+                                       uid=req.uid)
                 pages = self.slot_pages[slot]
                 self._table[slot, :] = NULL_PAGE
                 self._table[slot, : len(pages)] = pages
@@ -1315,7 +1360,7 @@ class ServingEngine:
                     self.registry.register(
                         job.prompt_key, pages[: math.ceil(job.S / ps)])
             else:
-                self._write_slot(slot, job.cache1)
+                self._write_slot(slot, job.cache1, req.uid)
             self.slot_prefill[slot] = None
             self._start_slot(slot, req, job.S, tok, job.tokens, job.resumed)
 
@@ -1460,10 +1505,12 @@ class ServingEngine:
             self._table[slot, logical_page] = new
             self.stats.cow_copies += 1
 
-    def _write_slot_paged(self, slot: int, cache1, start: int, stop: int):
+    def _write_slot_paged(self, slot: int, cache1, start: int, stop: int,
+                          uid: int):
         """Scatter a batch-1 contiguous prefill cache into this slot's pages
         (positions [start, stop)); non-paged leaves (sliding-window rings,
-        recurrent states) use the per-slot insert."""
+        recurrent states) use the per-slot insert, in place where the stack
+        carries state (``_insert_state``)."""
         ps = self.page_size
         pos_w = np.arange(start, stop)
         for lp in sorted({int(p) // ps for p in pos_w}):
@@ -1483,9 +1530,11 @@ class ServingEngine:
                         new[pk] = write_pages(
                             entry[pk], phys, off, one[ck][:, 0, pos_w])
                 lst[i] = new
-            else:
+            elif not self.carries_state:
                 lst[i] = jax.tree.map(
                     functools.partial(self._ins_slot, slot), entry, one)
+        if self.carries_state:
+            self._insert_state(slot, cache1, uid)
         if self.slot_xpages is not None:
             self._write_slot_xpages(slot, cache1)
 
@@ -1530,10 +1579,35 @@ class ServingEngine:
         idx[axis] = slice(slot, slot + 1)
         return pool.at[tuple(idx)].set(one.astype(pool.dtype))
 
-    def _write_slot(self, slot: int, cache1):
+    def _write_slot(self, slot: int, cache1, uid: int):
         """Copy a batch-1 cache into pool slot `slot` (batch axis index)."""
+        if self.carries_state:
+            self._insert_state(slot, cache1, uid)
+            return
         self.cache = jax.tree.map(
             functools.partial(self._ins_slot, slot), self.cache, cache1)
+
+    def _insert_state(self, slot: int, cache1, uid: int):
+        """Write a finished prefill's non-paged leaves — the stacked SSM
+        state, conv states, any contiguous KV — into slot ``slot``, in place:
+        one jitted update whose pool is donated, so the state (9.7 GB at
+        128 rows of granite-4.0-h-micro) is never copied whole.  The row is
+        overwritten entirely, so a reused slot starts from the prefill's
+        state, never from its previous occupant's."""
+        entries = list(self._cache_entries())
+        rows = [n for n, (_, _, e) in enumerate(entries)
+                if not (isinstance(e, dict) and "k_pages" in e)]
+        c1 = self._c1_entries(cache1)
+        with TraceAnnotation("engine.state_insert", uid=uid, slot=slot):
+            pool = {"ssm": self.cache["ssm"],
+                    "rows": [entries[n][2] for n in rows]}
+            one = {"ssm": cache1["ssm"], "rows": [c1[n] for n in rows]}
+            new = self._insert_state_rows(pool, one, jnp.int32(slot))
+            self.cache["ssm"] = new["ssm"]
+            for n, entry in zip(rows, new["rows"]):
+                lst, i, _ = entries[n]
+                lst[i] = entry
+            self.stats.state_inserts += 1
 
     def _sample(self, logits, temperature: float):
         if temperature <= 0.0:

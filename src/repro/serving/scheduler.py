@@ -27,6 +27,15 @@ same positions, same params — attention over a causal prefix is a pure
 function of both), so the rewrite is a no-op and the last logits row is
 exactly the full-prefill logits row.  Prompts shorter than one chunk
 take the ordinary prefill path and never reach ``chunk_spans``.
+
+Stacks that carry state (Mamba-2 layers) cannot re-process: the overlapped
+tokens would enter the recurrent state twice.  For them the spans do not
+overlap, and the engine pads the last one to the chunk width with
+positions that change no state (``models/mamba2.py``), so every chunk is
+one compiled ``(1, C)`` program whatever the prompt length.  The padded
+positions' KV lands in the private cache past the prompt, which the engine
+never copies into pages, and the engine rounds ``max_len`` up to whole
+chunks so that no padded write wraps the cache.
 """
 
 from __future__ import annotations
@@ -35,7 +44,8 @@ import dataclasses
 from typing import Any, List, Optional, Tuple
 
 
-def chunk_spans(S: int, chunk: int) -> List[Tuple[int, int]]:
+def chunk_spans(S: int, chunk: int,
+                carry_state: bool = False) -> List[Tuple[int, int]]:
     """Token spans ``[start, stop)`` that chunked prefill runs over a
     prompt of ``S`` tokens with chunk size ``chunk``.
 
@@ -46,11 +56,17 @@ def chunk_spans(S: int, chunk: int) -> List[Tuple[int, int]]:
     ``[0, S)`` exactly once in order, no span exceeds ``chunk`` tokens,
     the last span ends at ``S``, and a span never starts past the end of
     the previous one (re-processing, never a gap).
+
+    ``carry_state`` (a stack with recurrent state): spans start at
+    multiples of ``chunk`` and never overlap; the last one ends at ``S``
+    and may be shorter (the engine pads it).
     """
     if S <= 0:
         raise ValueError(f"prompt length must be positive, got {S}")
     if chunk <= 0:
         raise ValueError(f"chunk must be positive, got {chunk}")
+    if carry_state:
+        return [(a, min(a + chunk, S)) for a in range(0, S, chunk)]
     if S <= chunk:
         return [(0, S)]
     spans = [(i * chunk, (i + 1) * chunk) for i in range(S // chunk)]

@@ -177,3 +177,27 @@ def test_meshed_kernels_run_per_shard(topo, monkeypatch):
 
     text = jax.jit(step).lower(attn_args, ffn_args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_ssm_decode_updates_the_stacked_state_in_place(sds):
+    """granite-4.0-h-micro's decode state update at its published shape:
+    the float32 state of 36 Mamba layers stacked (128 rows, state 128, 64
+    heads of 64; 9.66 GB), one layer updated per call.  The stack is
+    aliased to the kernel's state output and the compiled call holds no
+    copy of it."""
+    L, B, N, HP = 36, 128, 128, 64 * 64
+    f32 = jnp.float32
+
+    def update(state, layer, x, dt, a, d, b, c):
+        return ops.ssm_decode(state, layer, x, dt, a, d, b, c, interpret=False)
+
+    compiled = jax.jit(update, donate_argnums=(0,)).lower(
+        sds((L, B, N, HP), f32), sds((), jnp.int32), sds((B, HP), f32),
+        sds((B, HP), f32), sds((HP,), f32), sds((HP,), f32), sds((B, N), f32),
+        sds((B, N), f32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "output_to_operand_aliasing={{1}: (1, {})}" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == L * B * N * HP * 4
+    assert mem.temp_size_in_bytes < 64 << 20
