@@ -94,9 +94,7 @@ def _mamba_layer(x, p, model, act_dtype):
     h = act(_rms(x, p["ln1"], m["norm_eps"]))
     z, xbc, dt = h @ p["w_z"], h @ p["w_xbc"], h @ p["w_dt"]
     padded = jnp.concatenate([jnp.zeros((K - 1, xbc.shape[1])), xbc])
-    xbc = sum(padded[k:k + S] * p["conv_w"][k] for k in range(K))
-    if m["mamba_conv_bias"]:
-        xbc = xbc + p["conv_b"]
+    xbc = sum(padded[k:k + S] * p["conv_w"][k] for k in range(K)) + p["conv_b"]
     xbc = jax.nn.silu(xbc)
     xs, b, c = xbc[:, :di], xbc[:, di:di + N], xbc[:, di + N:]
     dt = jax.nn.softplus(dt + p["dt_bias"])

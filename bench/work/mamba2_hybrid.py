@@ -57,7 +57,7 @@ class Work:
         sh, sp, N, K = (m["mamba_n_heads"], m["mamba_d_head"],
                         m["mamba_d_state"], m["mamba_d_conv"])
         di = sh * sp
-        cd = di + 2 * m["mamba_n_groups"] * N
+        cd = di + 2 * N  # x, then B and C of one group
         b, q = plan["block"], plan["q_prune"]
         mlp = [("w_gate", d, f), ("w_up", d, f), ("w_down", f, d)]
 
@@ -73,7 +73,7 @@ class Work:
                           ("conv_w", K, cd), ("w_out", di, d)] + mlp, n_m)
         attn = matrices([("wq", d, H * hd), ("wk", d, KVH * hd),
                          ("wv", d, KVH * hd), ("wo", H * hd, d)] + mlp, n_a)
-        vectors = 3 * sh + di + (cd if m["mamba_conv_bias"] else 0)
+        vectors = 3 * sh + di + cd  # dt_bias, A_log, D, norm, conv bias
         emb = [Matrix("tok", m["vocab"], d, 1,
                       serve_kind("tok", (m["vocab"], d), plan), 0, b)]
         if not m["tied"]:

@@ -74,17 +74,14 @@ class ModelConfig:
     lru_dim: int = 0  # RG-LRU width (recurrentgemma: ~d_model)
     # Mamba-2 mixer (``mamba`` layer kind, models/mamba2.py), under the
     # source's names: heads of ``mamba_d_head`` channels (d_inner =
-    # n_heads * d_head = expand * d_model), a state of ``mamba_d_state``
-    # per channel, B and C shared by the heads of each of ``mamba_n_groups``
-    # groups, a causal depthwise conv of width ``mamba_d_conv`` over
-    # (x, B, C), and the SSD scan over chunks of ``mamba_chunk_size``.
+    # n_heads * d_head), a state of ``mamba_d_state`` per channel, B and C
+    # of one group shared by all heads, a causal depthwise conv (with bias)
+    # of width ``mamba_d_conv`` over (x, B, C), and the SSD scan over
+    # chunks of ``mamba_chunk_size``.
     mamba_n_heads: int = 0
     mamba_d_head: int = 64
     mamba_d_state: int = 128
-    mamba_n_groups: int = 1
-    mamba_expand: int = 2
     mamba_d_conv: int = 4
-    mamba_conv_bias: bool = True
     mamba_chunk_size: int = 256
 
     # numerics
@@ -104,8 +101,8 @@ class ModelConfig:
 
     @property
     def mamba_conv_dim(self) -> int:
-        """Channels of the conv: x, then B and C of every group."""
-        return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+        """Channels of the conv: x, then B and C (one group)."""
+        return self.mamba_d_inner + 2 * self.mamba_d_state
 
     @property
     def layer_kinds(self) -> tuple:
@@ -143,7 +140,7 @@ class ModelConfig:
             elif k == "mamba":
                 di, cd, nh = self.mamba_d_inner, self.mamba_conv_dim, self.mamba_n_heads
                 total += d * (di + cd + nh) + di * d  # in_proj, out_proj
-                total += cd * (self.mamba_d_conv + int(self.mamba_conv_bias))
+                total += cd * (self.mamba_d_conv + 1)  # conv weight and bias
                 total += 3 * nh + di + 2 * d  # dt_bias, A_log, D, norms
                 total += per_dense_ffn
             elif k == "mlstm":

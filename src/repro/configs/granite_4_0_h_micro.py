@@ -33,10 +33,7 @@ CONFIG = ModelConfig(
     mamba_n_heads=64,
     mamba_d_head=64,
     mamba_d_state=128,
-    mamba_n_groups=1,
-    mamba_expand=2,
     mamba_d_conv=4,
-    mamba_conv_bias=True,
     mamba_chunk_size=256,
 )
 
@@ -60,10 +57,7 @@ SMOKE = ModelConfig(
     mamba_n_heads=8,
     mamba_d_head=16,
     mamba_d_state=16,
-    mamba_n_groups=1,
-    mamba_expand=2,
     mamba_d_conv=4,
-    mamba_conv_bias=True,
     mamba_chunk_size=16,
     compute_dtype="float32",
 )

@@ -43,18 +43,7 @@ from repro.models import layers as L
 _HI = jax.lax.Precision.HIGHEST
 
 
-def _check(cfg):
-    if cfg.mamba_n_groups != 1:
-        raise NotImplementedError(
-            f"{cfg.name}: Mamba-2 with {cfg.mamba_n_groups} groups (one only)")
-    if cfg.mamba_d_inner != cfg.mamba_expand * cfg.d_model:
-        raise ValueError(
-            f"{cfg.name}: mamba_n_heads * mamba_d_head = {cfg.mamba_d_inner} "
-            f"!= mamba_expand * d_model = {cfg.mamba_expand * cfg.d_model}")
-
-
 def init_mamba2(cfg, key):
-    _check(cfg)
     d, H = cfg.d_model, cfg.mamba_n_heads
     di, cd, K = cfg.mamba_d_inner, cfg.mamba_conv_dim, cfg.mamba_d_conv
     ks = jax.random.split(key, 6)
@@ -62,7 +51,7 @@ def init_mamba2(cfg, key):
     # published initial ranges)
     dt0 = jnp.exp(jax.random.uniform(ks[4], (H,), jnp.float32,
                                      jnp.log(1e-3), jnp.log(1e-1)))
-    p = {
+    return {
         "w_z": L.dense_init(ks[0], (d, di)),
         "w_xbc": L.dense_init(ks[1], (d, cd)),
         "w_dt": L.dense_init(ks[2], (d, H)),
@@ -72,19 +61,15 @@ def init_mamba2(cfg, key):
         "D": jnp.ones((H,), jnp.float32),
         "norm": jnp.zeros((di,), jnp.float32),  # gated norm, (1 + scale)
         "w_out": L.dense_init(jax.random.fold_in(key, 7), (di, d)),
+        "conv_b": jnp.zeros((cd,), jnp.float32),
     }
-    if cfg.mamba_conv_bias:
-        p["conv_b"] = jnp.zeros((cd,), jnp.float32)
-    return p
 
 
-def mamba2_axes(cfg):
-    a = {"w_z": ("d", "ff"), "w_xbc": ("d", None), "w_dt": ("d", None),
-         "conv_w": (None, None), "dt_bias": (None,), "A_log": (None,),
-         "D": (None,), "norm": ("ff",), "w_out": ("ff", "d")}
-    if cfg.mamba_conv_bias:
-        a["conv_b"] = (None,)
-    return a
+def mamba2_axes():
+    return {"w_z": ("d", "ff"), "w_xbc": ("d", None), "w_dt": ("d", None),
+            "conv_w": (None, None), "conv_b": (None,), "dt_bias": (None,),
+            "A_log": (None,), "D": (None,), "norm": ("ff",),
+            "w_out": ("ff", "d")}
 
 
 def init_conv_state(cfg, batch: int, dtype):
@@ -121,8 +106,7 @@ def _conv(xbc, w, b, state, n_valid):
     full = jnp.concatenate([state.astype(xbc.dtype), xbc], axis=1)
     w = w.astype(jnp.float32)
     y = sum(full[:, k:k + T].astype(jnp.float32) * w[k] for k in range(K))
-    if b is not None:
-        y = y + b.astype(jnp.float32)
+    y = y + b.astype(jnp.float32)
     if n_valid is None:
         new = full[:, T:]
     else:
@@ -183,7 +167,6 @@ def apply_mamba2(cfg, p, h, cache=None, *, layer=None, valid=None):
     "ssm": (L, B, N, H*P) every layer's state}; ``layer`` indexes this
     layer's state in the stack.  ``valid`` (B, T) bool marks real positions
     (a prefix of each row)."""
-    _check(cfg)
     B, T, _ = h.shape
     dt_c = h.dtype
     H, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
@@ -194,7 +177,7 @@ def apply_mamba2(cfg, p, h, cache=None, *, layer=None, valid=None):
     conv = (cache["conv"] if cache is not None
             else jnp.zeros((B, cfg.mamba_d_conv - 1, xbc.shape[-1]), dt_c))
     n_valid = None if valid is None else jnp.sum(valid, axis=1).astype(jnp.int32)
-    xbc, conv = _conv(xbc, p["conv_w"], p.get("conv_b"), conv, n_valid)
+    xbc, conv = _conv(xbc, p["conv_w"], p["conv_b"], conv, n_valid)
     x = xbc[..., :di]
     bm, cm = xbc[..., di:di + N], xbc[..., di + N:]
     dt = jax.nn.softplus(dt + p["dt_bias"].astype(jnp.float32))

@@ -133,7 +133,7 @@ def layer_axes(cfg, kind: str):
     if kind == "rec":
         return {"ln1": na, "rec": R.rglru_axes(), "ln2": na, "mlp": L.mlp_axes(cfg)}
     if kind == "mamba":
-        return {"ln1": na, "mamba": MB.mamba2_axes(cfg), "ln2": na,
+        return {"ln1": na, "mamba": MB.mamba2_axes(), "ln2": na,
                 "mlp": L.mlp_axes(cfg)}
     if kind == "mlstm":
         return {"ln": na, "cell": S.mlstm_axes(cfg)}

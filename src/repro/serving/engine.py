@@ -274,6 +274,16 @@ class Request:
             self.done = True
 
 
+@functools.partial(jax.jit, static_argnums=(3,), donate_argnums=(0,))
+def _set_rows(pool, one, slot, axes):
+    """Each leaf of ``pool`` with the matching leaf of ``one`` written at
+    ``slot`` along its batch axis (``axes``, in leaf order)."""
+    leaves, tree = jax.tree.flatten(pool)
+    return tree.unflatten([
+        jax.lax.dynamic_update_slice_in_dim(p, o.astype(p.dtype), slot, axis=a)
+        for p, o, a in zip(leaves, tree.flatten_up_to(one), axes)])
+
+
 @dataclasses.dataclass
 class EngineStats:
     prefills: int = 0
@@ -315,8 +325,9 @@ class EngineStats:
     # plain-tick rows whose token came from the tick's one batched greedy
     # choice (equals decode_tokens when every request is greedy)
     batched_choices: int = 0
-    # finished prefills whose recurrent state (Mamba-2) was written into
-    # their slot in place, one per ``engine.state_insert`` span
+    # finished prefills whose per-slot rows (recurrent state, contiguous
+    # caches) were written into their slot in place, one per
+    # ``engine.state_insert`` span
     state_inserts: int = 0
 
     @property
@@ -721,13 +732,6 @@ class ServingEngine:
             logits = jnp.where(poison[:, None, None], jnp.nan, logits)
             return logits, finite_rows(logits), cache
 
-        # a finished prefill's state into its slot, in place: the pool is
-        # donated and each leaf takes the batch-1 row at its axis 1
-        self._insert_state_rows = jax.jit(
-            lambda pool, one, slot: jax.tree.map(
-                lambda p, o: jax.lax.dynamic_update_slice_in_dim(
-                    p, o.astype(p.dtype), slot, axis=1), pool, one),
-            donate_argnums=(0,))
         if self.mesh is None:
             self._decode = jax.jit(_decode_impl, donate_argnums=(1,))
             self._prefill1 = jax.jit(functools.partial(self._prefill_one_impl, cfg))
@@ -1038,8 +1042,7 @@ class ServingEngine:
         )
         batch = {"tokens": jnp.asarray(tokens, jnp.int32)[None]}
         _, cache1 = self._draft_prefill1(self.draft_params, batch, cache1)
-        self.draft_cache = jax.tree.map(
-            functools.partial(self._ins_slot, slot), self.draft_cache, cache1)
+        self.draft_cache = self._insert_rows(self.draft_cache, cache1, slot)
 
     def _start_slot(self, slot: int, req: Request, S: int, first_tok: int,
                     tokens: np.ndarray, resumed: bool):
@@ -1509,8 +1512,8 @@ class ServingEngine:
                           uid: int):
         """Scatter a batch-1 contiguous prefill cache into this slot's pages
         (positions [start, stop)); non-paged leaves (sliding-window rings,
-        recurrent states) use the per-slot insert, in place where the stack
-        carries state (``_insert_state``)."""
+        recurrent states) take the per-slot row insert
+        (``_insert_slot_rows``)."""
         ps = self.page_size
         pos_w = np.arange(start, stop)
         for lp in sorted({int(p) // ps for p in pos_w}):
@@ -1530,11 +1533,7 @@ class ServingEngine:
                         new[pk] = write_pages(
                             entry[pk], phys, off, one[ck][:, 0, pos_w])
                 lst[i] = new
-            elif not self.carries_state:
-                lst[i] = jax.tree.map(
-                    functools.partial(self._ins_slot, slot), entry, one)
-        if self.carries_state:
-            self._insert_state(slot, cache1, uid)
+        self._insert_slot_rows(slot, cache1, uid)
         if self.slot_xpages is not None:
             self._write_slot_xpages(slot, cache1)
 
@@ -1568,42 +1567,55 @@ class ServingEngine:
 
     # -- contiguous-slot plumbing ---------------------------------------------
 
-    def _ins_slot(self, slot: int, pool, one):
-        # batch axis position differs per leaf family: attn caches are
-        # (..., B, S, KVH, hd) with B at -4; recurrent states keep B
-        # first. We locate the axis whose size == max_batch.
-        axis = next(
-            i for i, s in enumerate(pool.shape) if s == self.max_batch and one.shape[i] == 1
-        )
-        idx = [slice(None)] * pool.ndim
-        idx[axis] = slice(slot, slot + 1)
-        return pool.at[tuple(idx)].set(one.astype(pool.dtype))
+    def _insert_rows(self, pool, one, slot: int):
+        """``pool`` with the batch-1 tree ``one`` written into row ``slot``,
+        in place: one jitted update whose pool is donated, so no leaf is
+        copied whole (the stacked Mamba state is 9.7 GB at 128 rows of
+        granite-4.0-h-micro).  A leaf's batch axis is the one of size
+        ``max_batch`` where ``one``'s is 1: attention caches keep it at -4,
+        recurrent states first, the stacked Mamba state second.  The row is
+        overwritten whole, so a reused slot keeps nothing of its previous
+        occupant.  A buffer can be donated once only, so a leaf that
+        shares its buffer with an earlier one (an init cache's k and v
+        zeros) is copied first."""
+        leaves, tree = jax.tree.flatten(pool)
+        seen = set()
+        for n, p in enumerate(leaves):
+            if id(p) in seen:
+                leaves[n] = jnp.copy(p)
+            seen.add(id(p))
+        pool = tree.unflatten(leaves)
+        axes = tuple(
+            next(i for i, n in enumerate(p.shape)
+                 if n == self.max_batch and o.shape[i] == 1)
+            for p, o in zip(leaves, tree.flatten_up_to(one)))
+        return _set_rows(pool, one, jnp.int32(slot), axes)
 
     def _write_slot(self, slot: int, cache1, uid: int):
         """Copy a batch-1 cache into pool slot `slot` (batch axis index)."""
-        if self.carries_state:
-            self._insert_state(slot, cache1, uid)
-            return
-        self.cache = jax.tree.map(
-            functools.partial(self._ins_slot, slot), self.cache, cache1)
+        with TraceAnnotation("engine.state_insert", uid=uid, slot=slot):
+            self.cache = self._insert_rows(self.cache, cache1, slot)
+            self.stats.state_inserts += 1
 
-    def _insert_state(self, slot: int, cache1, uid: int):
-        """Write a finished prefill's non-paged leaves — the stacked SSM
-        state, conv states, any contiguous KV — into slot ``slot``, in place:
-        one jitted update whose pool is donated, so the state (9.7 GB at
-        128 rows of granite-4.0-h-micro) is never copied whole.  The row is
-        overwritten entirely, so a reused slot starts from the prefill's
-        state, never from its previous occupant's."""
+    def _insert_slot_rows(self, slot: int, cache1, uid: int):
+        """Write a finished prefill's non-paged leaves (the stacked Mamba
+        state, conv states, sliding-window rings) into row ``slot`` of the
+        paged engine's cache (``_insert_rows``); a stack of paged layers
+        alone has none."""
         entries = list(self._cache_entries())
+        c1 = self._c1_entries(cache1)
         rows = [n for n, (_, _, e) in enumerate(entries)
                 if not (isinstance(e, dict) and "k_pages" in e)]
-        c1 = self._c1_entries(cache1)
+        pool = {"rows": [entries[n][2] for n in rows]}
+        one = {"rows": [c1[n] for n in rows]}
+        if "ssm" in self.cache:
+            pool["ssm"], one["ssm"] = self.cache["ssm"], cache1["ssm"]
+        if not jax.tree.leaves(pool):
+            return
         with TraceAnnotation("engine.state_insert", uid=uid, slot=slot):
-            pool = {"ssm": self.cache["ssm"],
-                    "rows": [entries[n][2] for n in rows]}
-            one = {"ssm": cache1["ssm"], "rows": [c1[n] for n in rows]}
-            new = self._insert_state_rows(pool, one, jnp.int32(slot))
-            self.cache["ssm"] = new["ssm"]
+            new = self._insert_rows(pool, one, slot)
+            if "ssm" in new:
+                self.cache["ssm"] = new["ssm"]
             for n, entry in zip(rows, new["rows"]):
                 lst, i, _ = entries[n]
                 lst[i] = entry

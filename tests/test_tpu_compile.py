@@ -201,3 +201,70 @@ def test_ssm_decode_updates_the_stacked_state_in_place(sds):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == L * B * N * HP * 4
     assert mem.temp_size_in_bytes < 64 << 20
+
+
+def test_hybrid_decode_step_memory_by_layer_layout(sds, monkeypatch):
+    """granite-4.0-h-micro's decode step at the cell's cache sizes (128
+    rows, 3072 positions in 24577 int8 pages of 16, 36 Mamba layers of
+    state stacked: 11.6 GB) with small weight widths, compiled in the
+    served layout (``find_unit``: a one-layer Mamba unit scanned 5 times,
+    then the other 35 layers as 8 scanned runs) and with the published
+    10-layer period as the unit scanned 4 times.  Served, the stacked state
+    is only carried and aliased (no op makes a copy or a slice of its
+    shape).  The period's scan takes the attention layers' pools through
+    its per-layer inputs and outputs, which allocates them anew (4 stacked
+    layers, 1.8 GB): its temporaries exceed the served layout's by at
+    least that much, for 10 distinct layer bodies against the served 9."""
+    import re
+    import dataclasses
+
+    from repro.models import transformer as T
+    from repro.models.api import get_api
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    period = ("mamba",) * 5 + ("global",) + ("mamba",) * 4
+    cfg = dataclasses.replace(
+        C.get_config("granite-4.0-h-micro", smoke=True), n_layers=40,
+        pattern=period * 4, d_model=256, n_heads=32, n_kv_heads=8,
+        head_dim=64, d_ff=512, vocab=512, mamba_n_heads=64, mamba_d_head=64,
+        mamba_d_state=128, compute_dtype="bfloat16")
+    api = get_api(cfg)
+    B, S, PS = 128, 3072, 16
+    pages = 1 + B * S // PS
+    state = f"f32[36,{B},128,4096]"
+    pool_bytes = 4 * 2 * pages * 8 * PS * (64 + 4)  # k, v int8 and their scales
+
+    def compile_step():
+        params = jax.eval_shape(
+            lambda: api.init_params(cfg, jax.random.key(0)))
+        cache = jax.eval_shape(lambda: api.init_cache(
+            cfg, B, S, jnp.bfloat16, kv_dtype=jnp.int8, page_size=PS,
+            num_pages=pages))
+        put = lambda t: jax.tree.map(lambda a: sds(a.shape, a.dtype), t)
+
+        def step(params, cache, tokens, pos):
+            return api.decode_step(cfg, params, cache, tokens, pos)
+
+        return jax.jit(step, donate_argnums=(1,)).lower(
+            put(params), put(cache), sds((B, 1), jnp.int32),
+            sds((B,), jnp.int32)).compile()
+
+    prev = layers.force_attention_kernel(True)
+    try:
+        served = compile_step()
+        monkeypatch.setattr(T, "find_unit", lambda kinds: (period, 4, ()))
+        by_period = compile_step()
+    finally:
+        layers.force_attention_kernel(prev)
+    text = served.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    made = {m.group(1) for m in re.finditer(
+        r"= " + re.escape(state) + r"\S* ([\w-]+)\(", text)}
+    assert made <= {"parameter", "get-tuple-element"}, made
+    mem, mem_p = served.memory_analysis(), by_period.memory_analysis()
+    print(f"served: arguments {mem.argument_size_in_bytes}, temporaries "
+          f"{mem.temp_size_in_bytes}; 10-layer period: arguments "
+          f"{mem_p.argument_size_in_bytes}, temporaries "
+          f"{mem_p.temp_size_in_bytes}")
+    assert mem.alias_size_in_bytes >= 36 * B * 128 * 4096 * 4 + pool_bytes
+    assert mem_p.temp_size_in_bytes - mem.temp_size_in_bytes >= pool_bytes
